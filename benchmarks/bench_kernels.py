@@ -1,6 +1,6 @@
-"""Kernel micro-benchmarks (Pallas interpret mode on CPU — numbers are
-correctness-path timings, NOT TPU performance; the TPU roofline for these
-kernels is derived analytically in EXPERIMENTS.md §Roofline)."""
+"""Kernel micro-benchmarks. Off a TPU the kernels run in the Pallas
+interpreter and the numbers are correctness-path timings, NOT TPU
+performance; on a TPU backend they are compiled (the backend decides)."""
 from __future__ import annotations
 
 import time
@@ -65,7 +65,7 @@ def main(report):
     q = jnp.asarray(rng.normal(size=(4, 1024, 64)), jnp.bfloat16)
     from repro.kernels.flash_attention import flash_attention_pallas
     us = _time(flash_attention_pallas, q, q, q, causal=True, block_q=256,
-               block_k=256, interpret=True)
+               block_k=256, interpret=jax.default_backend() != "tpu")
     flops = 4 * 1024 * 1024 * 64 * 2 * 2 / 2  # causal half
     report("flash_attn_4x1k_d64", us,
            f"{flops/1e9:.1f} GFLOP; MXU target {flops/197e12*1e6:.1f} us on v5e")

@@ -18,9 +18,12 @@ Two rows:
     with 8 spines (80 switches, ~1k workers) on a coarse uniform grid,
     single-device vs the 8-way sharded ``shard_map`` runner (per-shard
     transit rings shrink the dominant arrival-sort axis; the frontier is
-    the only cross-shard exchange). Runs in a subprocess with
-    ``--xla_force_host_platform_device_count=8`` so the parent process's
-    device count doesn't matter. Floor-gated: sharded ≥ 2× single-device
+    the only cross-shard exchange). Runs in a subprocess on the CPU
+    (``JAX_PLATFORMS=cpu``) with ``--xla_force_host_platform_device_count=8``
+    so the parent process's device count doesn't matter — and so the child
+    never competes with the parent for an accelerator the parent holds.
+    These rows are CPU counts by construction and say so in their output
+    (``platform``). Floor-gated: sharded ≥ 2× single-device
     boundaries/s on the k=8 row, and the single-device rate itself ≥ 1×
     a conservatively recorded baseline (``vecsim_scale_base``). The two
     runs must agree bitwise (asserted in-child).
@@ -49,6 +52,7 @@ import jax
 from repro.core import vecsim
 from repro.core.topology import build_sim_cfg, fattree_spec
 
+assert jax.default_backend() == "cpu", jax.default_backend()
 k, spines, wpc, dim, reps = map(int, sys.argv[1:6])
 spec = fattree_spec(k, spines=spines)
 cfg = build_sim_cfg(spec, clusters_per_ingress=2, workers_per_cluster=wpc,
@@ -75,7 +79,7 @@ assert np.array_equal(r1.delivered_payloads, rs.delivered_payloads)
 assert r1.aom == rs.aom and r1.sim.queue_stats == rs.sim.queue_stats
 n = max(len(r1.sim.delivered_updates), 1)
 print(json.dumps(dict(
-    switches=len(spec.switches), workers=len(cfg.workers),
+    platform="cpu", switches=len(spec.switches), workers=len(cfg.workers),
     devices=min(8, ndev), n_steps=int(r1.n_steps), delivered=n,
     wall_1dev_s=t1, wall_shard_s=ts,
     rate_1dev=r1.n_steps / t1, rate_shard=rs.n_steps / ts,
@@ -87,9 +91,10 @@ print(json.dumps(dict(
 def vecsim_scale_row(k: int, spines: int, wpc: int, dim: int = 64,
                      reps: int = 2) -> dict:
     """One (switches x devices) scale row, measured in a child process
-    with 8 forced host-platform devices (jax device count is fixed at
-    import time, so the parent cannot retrofit it)."""
-    env = dict(os.environ)
+    on 8 forced host-platform CPU devices (jax device count is fixed at
+    import time, so the parent cannot retrofit it; and a parent that has
+    touched an accelerator holds it, so the child stays on the CPU)."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env["PYTHONPATH"] = os.path.join(root, "src") + os.pathsep + \
         env.get("PYTHONPATH", "")
@@ -180,8 +185,9 @@ def main(report):
         r = vecsim_scale_row(k, spines, wpc)
         rows[label] = r
         report(f"vecsim_scale_{label}", r["wall_shard_s"] * 1e6,
-               f"{r['switches']}sw x {r['devices']}dev, {r['workers']} "
-               f"workers: {r['rate_1dev']:.1f} -> {r['rate_shard']:.1f} "
+               f"{r['switches']}sw x {r['devices']} {r['platform']} dev, "
+               f"{r['workers']} workers: {r['rate_1dev']:.1f} -> "
+               f"{r['rate_shard']:.1f} "
                f"boundaries/s = {r['speedup']:.2f}x sharded; "
                f"h2d/delivery {r['h2d_per_delivery']:.2f}; bitwise")
     k8 = rows["k8"]

@@ -4,19 +4,24 @@ Prints ``name,us_per_call,derived`` CSV lines (one per artifact), writes the
 full structured results to experiments/bench_results.json, and persists the
 per-benchmark microseconds of each module to experiments/BENCH_<module>.json
 (e.g. BENCH_queue.json, BENCH_kernels.json) so the perf trajectory is
-tracked across PRs — see benchmarks/README.md for how to read them.
+tracked across PRs — see benchmarks/README.md for how to read them. A
+suite that raises is reported as ``<suite>_ERROR``; every suite's JSON is
+still written, and the harness then exits nonzero.
 """
 from __future__ import annotations
 
 import json
 import sys
 import time
+import traceback
 from pathlib import Path
 
 OUT_DIR = Path(__file__).resolve().parents[1] / "experiments"
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (bench_failures, bench_kernels, bench_multihop,
                             bench_queue, bench_roofline, bench_step,
                             bench_train, bench_training, bench_vecsim,
@@ -49,6 +54,7 @@ def main() -> None:
         sys.exit(f"unknown suite {only!r}; pick one of "
                  f"{', '.join(n for n, _ in modules)}")
     OUT_DIR.mkdir(exist_ok=True)
+    failed = []
     for name, mod in modules:
         if only and only != name:
             continue
@@ -57,8 +63,10 @@ def main() -> None:
         try:
             results[name] = mod.main(report)
         except Exception as e:  # noqa: BLE001 — keep the suite running
+            traceback.print_exc()
             report(f"{name}_ERROR", 0.0, f"{type(e).__name__}: {e}")
             results[name] = {"error": str(e)}
+            failed.append(name)
         report(f"{name}_total", (time.time() - t0) * 1e6, "suite wall time")
         (OUT_DIR / f"BENCH_{name}.json").write_text(
             json.dumps(timings, indent=1) + "\n")
@@ -73,6 +81,8 @@ def main() -> None:
         prev.update(results)
         results = prev
     out.write_text(json.dumps(results, indent=1, default=str))
+    if failed:
+        sys.exit(f"suites raised: {', '.join(failed)}")
 
 
 if __name__ == '__main__':

@@ -55,8 +55,10 @@ def demo_verifier():
 
 
 def demo_kernel():
-    print("== Pallas olaf_combine kernel (interpret mode) ==")
+    import jax
     import jax.numpy as jnp
+    print(f"== Pallas olaf_combine kernel ({jax.default_backend()}: compiled "
+          f"on tpu, interpreted elsewhere) ==")
     from repro.kernels import ops, ref
     slots = jnp.zeros((4, 256))
     counts = jnp.zeros((4,), jnp.int32)

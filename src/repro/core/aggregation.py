@@ -182,6 +182,7 @@ def jax_trimmed_combine(rows, weights, trim: float = 0.25):
     ``valid * agg_count`` weighting the plain path uses. Returns the
     winsorized weighted mean as ``(D,)`` float32.
     """
+    import jax
     import jax.numpy as jnp
 
     valid = weights > 0
@@ -194,4 +195,6 @@ def jax_trimmed_combine(rows, weights, trim: float = 0.25):
     clipped = jnp.clip(safe, jnp.nan_to_num(lo, nan=0.0),
                        jnp.nan_to_num(hi, nan=0.0))
     wts = weights * valid
-    return jnp.einsum("k,kd->d", wts, clipped) / jnp.maximum(wts.sum(), 1.0)
+    return jnp.einsum("k,kd->d", wts, clipped,
+                      precision=jax.lax.Precision.HIGHEST) \
+        / jnp.maximum(wts.sum(), 1.0)

@@ -87,7 +87,6 @@ from repro.core.olaf_queue import EVENT_OF_CLASS, PyOlafQueue, \
     burst_contribution_mask, _EV_AGG, _EV_DROP, _EV_RESET
 from repro.core.topology import TopologySpec, resolve_sim_cfg, \
     spec_from_switch_cfgs
-from repro.kernels.olaf_combine import _pick_tile_q as _largest_tile
 
 
 # Algorithm 1 class label -> device window event, through the shared
@@ -197,7 +196,7 @@ class HybridMultiSwitchDataPlane:
     def __init__(self, switch_cfgs=None, ingress_switches=(), dim: int = 0,
                  payload_rows: Sequence[np.ndarray] = (), *,
                  topology: Optional[TopologySpec] = None,
-                 interpret: bool = True, sharded: bool = False,
+                 sharded: bool = False,
                  flush_cadence: bool = True) -> None:
         assert topology is not None or switch_cfgs is not None
         self.spec = topology if topology is not None \
@@ -216,8 +215,6 @@ class HybridMultiSwitchDataPlane:
         self.slots_dev = jnp.zeros((S, Q, dim), jnp.float32)
         self.counts_dev = jnp.zeros((S, Q), jnp.int32)
         self.dim = dim
-        self.tile_d = _largest_tile(dim, 512)  # shared divisor-shrink rule
-        self.interpret = interpret
         self.sharded = sharded
         self._mesh = None
         if sharded:
@@ -673,8 +670,7 @@ class HybridMultiSwitchDataPlane:
                                   self.counts_dev)
             self.slots_dev, self.counts_dev = olaf_combine_sharded(
                 self.slots_dev, counts_in, updates, jnp.asarray(clusters),
-                jnp.asarray(gate), mesh=self._mesh, tile_d=self.tile_d,
-                interpret=self.interpret)
+                jnp.asarray(gate), mesh=self._mesh)
             if drain is not None:
                 drained = self._drain_only(*drain)
         elif drain is not None:
@@ -685,13 +681,12 @@ class HybridMultiSwitchDataPlane:
                 self.slots_dev, self.counts_dev, updates, clusters, gate,
                 reset_mask, np.asarray([s], np.int32),
                 np.asarray([slot], np.int32),
-                drain_hop=np.asarray([-1 if hop is None else hop], np.int32),
-                tile_d=self.tile_d, interpret=self.interpret)
+                drain_hop=np.asarray([-1 if hop is None else hop], np.int32))
             drained = rows[0]
         else:
             self.slots_dev, self.counts_dev = ops.olaf_combine_window(
                 self.slots_dev, self.counts_dev, updates, clusters, gate,
-                reset_mask, tile_d=self.tile_d, interpret=self.interpret)
+                reset_mask)
         return drained
 
     def _drain_only(self, s: int, slot: int) -> jnp.ndarray:
@@ -742,7 +737,6 @@ class HybridMultiSwitchDataPlane:
 
 
 def run_hybrid_multihop(dim: int = 256, *, seed: int = 0,
-                        interpret: bool = True,
                         payload_rows: Optional[Sequence[np.ndarray]] = None,
                         payload_source=None,
                         sim_cfg: Optional[SimCfg] = None,
@@ -878,7 +872,7 @@ def run_hybrid_multihop(dim: int = 256, *, seed: int = 0,
                                       sim_mesh=sim_mesh), cfg
     plane = HybridMultiSwitchDataPlane(
         cfg.switches, {w.ingress_switch for w in cfg.workers}, dim,
-        payload_rows, interpret=interpret, sharded=sharded,
+        payload_rows, sharded=sharded,
         flush_cadence=flush_cadence)
     if batched:
         plane.feed_window(events)

@@ -406,11 +406,12 @@ def jax_dequeue_burst(state: JaxQueueState, k: int
     _, slots = jax.lax.top_k(-state.seq, k)
     valid = state.cluster[slots] >= 0
     # one-hot gather (k, Q); invalid rows are zeroed so their payload is 0
-    # and they cannot clear a live slot.
+    # and they cannot clear a live slot. HIGHEST keeps the matmul an exact
+    # f32 copy on a TPU, whose default f32 matmul takes one bf16 pass
     onehot = ((slots[:, None] == jnp.arange(Q, dtype=slots.dtype)[None, :])
               & valid[:, None])
     payload = jnp.einsum("kq,qd->kd", onehot.astype(state.payload.dtype),
-                         state.payload)
+                         state.payload, precision=jax.lax.Precision.HIGHEST)
     out = dict(
         valid=valid,
         n_valid=valid.sum(),
@@ -606,8 +607,8 @@ def jax_enqueue_burst_ex(state: JaxQueueState, clusters, workers, gen_times,
     # weight k (all-ones in_counts degenerates to the 0/1 segment matrix).
     seg = ((onehot & contributes[:, None]).astype(jnp.float32)
            * in_counts.astype(jnp.float32)[:, None])  # (U, Q)
-    sums = jnp.einsum("uq,ud->qd", seg,
-                      payloads.astype(jnp.float32))  # the one-hot matmul
+    sums = jnp.einsum("uq,ud->qd", seg, payloads.astype(jnp.float32),
+                      precision=jax.lax.Precision.HIGHEST)  # one-hot matmul
 
     n_contrib = seg.sum(axis=0)  # (Q,)
     base_n = jnp.where(last_reset < 0, state.agg_count, 0).astype(jnp.float32)

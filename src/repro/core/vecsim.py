@@ -522,8 +522,33 @@ def _ring_insert_vec(ring, ovf, mask, rows):
     n_free = jnp.sum(free.astype(jnp.int32))
     ok = mask & (rank < n_free)
     slot = jnp.where(ok, forder[jnp.clip(rank, 0, R - 1)], R)
-    ring = {k: v.at[slot].set(rows[k], mode="drop") for k, v in ring.items()}
+    ring = {k: _place(v, slot, rows[k]) for k, v in ring.items()}
     return ring, ovf | jnp.any(mask & ~ok), slot
+
+
+def _append(buf, n, rows, k):
+    """Write ``rows[:k]`` to ``buf[n:n + k]``, dropping rows past the end
+    of ``buf``: one window read and one window write of ``min(len(rows),
+    len(buf))`` rows, with no out-of-range index (see :func:`_place`)."""
+    size, L = buf.shape[0], min(rows.shape[0], buf.shape[0])
+    start = jnp.clip(n, 0, size - L)
+    off = start + jnp.arange(L, dtype=jnp.int32) - n  # row of each window slot
+    take = ((off >= 0) & (off < k)).reshape((L,) + (1,) * (buf.ndim - 1))
+    win = lax.dynamic_slice_in_dim(buf, start, L)
+    new = jnp.where(take, rows[jnp.clip(off, 0, rows.shape[0] - 1)], win)
+    return lax.dynamic_update_slice_in_dim(buf, new, start, 0)
+
+
+def _place(buf, idx, rows):
+    """``buf.at[idx].set(rows, mode="drop")`` for distinct ``idx``, written
+    as a gather and a select: entries of ``idx`` outside ``buf`` are
+    dropped. On a TPU v5e the scatter form lost an in-range payload row
+    when the same scatter also carried out-of-range indices (the PS ring's
+    ``(S, D)`` rows inside the scan); the select has no such case."""
+    n = buf.shape[0]
+    hit = idx[None, :] == jnp.arange(n, dtype=idx.dtype)[:, None]  # (n, N)
+    keep = jnp.any(hit, axis=1).reshape((n,) + (1,) * (buf.ndim - 1))
+    return jnp.where(keep, rows[jnp.argmax(hit, axis=1)], buf)
 
 
 def _init_carry(static: _Static, *, n_s: Optional[int] = None,
@@ -778,15 +803,13 @@ def _make_runner(static: _Static):
             raw_drop_add = jnp.sum(jnp.where(dropped_now, srv["subs"], 0))
 
             orderd = jnp.argsort(jnp.where(dropped_now, fin, jnp.inf))
-            posd = jnp.argsort(orderd)
             drp = carry["drp"]
-            widx = jnp.where(dropped_now, drp["n"] + posd, Gd + 1)
+            n_drp = jnp.sum(dropped_now.astype(jnp.int32))
             drp = dict(
-                n=drp["n"] + jnp.sum(dropped_now.astype(jnp.int32)),
-                time=drp["time"].at[widx].set(fin, mode="drop"),
-                rcl=drp["rcl"].at[widx].set(srv["rcl"], mode="drop"),
-                gen=drp["gen"].at[widx].set(srv["gen"], mode="drop"),
-                subs=drp["subs"].at[widx].set(srv["subs"], mode="drop"))
+                n=drp["n"] + n_drp,
+                **{k: _append(drp[k], drp["n"], v[orderd], n_drp)
+                   for k, v in (("time", fin), ("rcl", srv["rcl"]),
+                                ("gen", srv["gen"]), ("subs", srv["subs"]))})
 
             ovf = carry["ovf"]
             ps, ovf_ps, _ = _ring_insert_vec(
@@ -816,19 +839,12 @@ def _make_runner(static: _Static):
             due = (ps["time"] <= t) & (ps["time"] <= horizon)
             n_due = jnp.sum(due.astype(jnp.int32))
             orderp = jnp.argsort(jnp.where(due, ps["time"], jnp.inf))
-            posp = jnp.argsort(orderp)
             dlv = carry["dlv"]
-            didx = jnp.where(due, dlv["n"] + posp, Gc + 1)
             dlv = dict(
                 n=dlv["n"] + n_due,
-                time=dlv["time"].at[didx].set(ps["time"], mode="drop"),
-                rcl=dlv["rcl"].at[didx].set(ps["rcl"], mode="drop"),
-                wk=dlv["wk"].at[didx].set(ps["wk"], mode="drop"),
-                gen=dlv["gen"].at[didx].set(ps["gen"], mode="drop"),
-                rw=dlv["rw"].at[didx].set(ps["rw"], mode="drop"),
-                agg=dlv["agg"].at[didx].set(ps["agg"], mode="drop"),
-                subs=dlv["subs"].at[didx].set(ps["subs"], mode="drop"),
-                pay=dlv["pay"].at[didx].set(ps["pay"], mode="drop"))
+                **{k: _append(dlv[k], dlv["n"], ps[k][orderp], n_due)
+                   for k in ("time", "rcl", "wk", "gen", "rw", "agg", "subs",
+                             "pay")})
             ts_b = ps["time"][orderp]
             gen_b = ps["gen"][orderp]
             due_b = due[orderp]
@@ -1144,7 +1160,6 @@ def _make_runner_sharded(static: _Static, ns: int, nw: int, rt_loc: int,
       gathered values — all integer or order-preserving, no cross-shard
       float reductions, so f32 bit patterns cannot diverge.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec
 
     S, W, C, CC, Q = static.S, static.W, static.C, static.CC, static.Q
@@ -1319,15 +1334,13 @@ def _make_runner_sharded(static: _Static, ns: int, nw: int, rt_loc: int,
             raw_drop_add = jnp.sum(jnp.where(drop_g, subs_g, 0))
 
             orderd = jnp.argsort(jnp.where(drop_g, fin_g, jnp.inf))
-            posd = jnp.argsort(orderd)
             drp = carry["drp"]
-            widx = jnp.where(drop_g, drp["n"] + posd, Gd + 1)
+            n_drp = jnp.sum(drop_g.astype(jnp.int32))
             drp = dict(
-                n=drp["n"] + jnp.sum(drop_g.astype(jnp.int32)),
-                time=drp["time"].at[widx].set(fin_g, mode="drop"),
-                rcl=drp["rcl"].at[widx].set(rcl_g, mode="drop"),
-                gen=drp["gen"].at[widx].set(gen_g, mode="drop"),
-                subs=drp["subs"].at[widx].set(subs_g, mode="drop"))
+                n=drp["n"] + n_drp,
+                **{k: _append(drp[k], drp["n"], v[orderd], n_drp)
+                   for k, v in (("time", fin_g), ("rcl", rcl_g),
+                                ("gen", gen_g), ("subs", subs_g))})
 
             ovf = carry["ovf"]
             ps, ovf_ps, _ = _ring_insert_vec(
@@ -1355,19 +1368,12 @@ def _make_runner_sharded(static: _Static, ns: int, nw: int, rt_loc: int,
             due = (ps["time"] <= t) & (ps["time"] <= horizon)
             n_due = jnp.sum(due.astype(jnp.int32))
             orderp = jnp.argsort(jnp.where(due, ps["time"], jnp.inf))
-            posp = jnp.argsort(orderp)
             dlv = carry["dlv"]
-            didx = jnp.where(due, dlv["n"] + posp, Gc + 1)
             dlv = dict(
                 n=dlv["n"] + n_due,
-                time=dlv["time"].at[didx].set(ps["time"], mode="drop"),
-                rcl=dlv["rcl"].at[didx].set(ps["rcl"], mode="drop"),
-                wk=dlv["wk"].at[didx].set(ps["wk"], mode="drop"),
-                gen=dlv["gen"].at[didx].set(ps["gen"], mode="drop"),
-                rw=dlv["rw"].at[didx].set(ps["rw"], mode="drop"),
-                agg=dlv["agg"].at[didx].set(ps["agg"], mode="drop"),
-                subs=dlv["subs"].at[didx].set(ps["subs"], mode="drop"),
-                pay=dlv["pay"].at[didx].set(ps["pay"], mode="drop"))
+                **{k: _append(dlv[k], dlv["n"], ps[k][orderp], n_due)
+                   for k in ("time", "rcl", "wk", "gen", "rw", "agg", "subs",
+                             "pay")})
             ts_b = ps["time"][orderp]
             gen_b = ps["gen"][orderp]
             due_b = due[orderp]
@@ -1639,8 +1645,8 @@ def _make_runner_sharded(static: _Static, ns: int, nw: int, rt_loc: int,
         q=SW, rdrops=SW, departed=SW, drops_s=SW, reroutes_s=SW, srv=SW,
         aom_avg=WK, dlv=RP, drp=RP, sent=RP, deferred=RP, link_dropped=RP,
         raw_link_dropped=RP, reroutes=RP, forwarded=RP, ovf=RP)
-    return jax.jit(shard_map(run, mesh=mesh, in_specs=(in_spec, RP),
-                             out_specs=out_specs, check_rep=False))
+    return jax.jit(jax.shard_map(run, mesh=mesh, in_specs=(in_spec, RP),
+                                 out_specs=out_specs, check_vma=False))
 
 
 def _mesh_shape(mesh) -> Tuple[int, int]:

@@ -304,11 +304,10 @@ def vecsim_mesh(n_switches=None, *, n_clusters: Optional[int] = None,
 
 def _shard_switch_axis(fn, mesh: Mesh, n_in: int, n_out: int):
     """shard_map ``fn`` (every operand/result leading-S) over ``"switch"``."""
-    from jax.experimental.shard_map import shard_map
     spec = P("switch")
-    return shard_map(fn, mesh=mesh, in_specs=(spec,) * n_in,
-                     out_specs=(spec,) * n_out if n_out > 1 else spec,
-                     check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec,) * n_in,
+                         out_specs=(spec,) * n_out if n_out > 1 else spec,
+                         check_vma=False)
 
 
 def olaf_combine_sharded(slots, counts, updates, clusters, gate, *,
@@ -359,15 +358,14 @@ def olaf_step_sharded(states, clusters, workers, gen_times, rewards,
     if mesh.devices.size <= 1:
         return fn(states, clusters, workers, gen_times, rewards, payloads,
                   thr, send, cap)
-    from jax.experimental.shard_map import shard_map
     spec = P("switch")
     state_specs = jax.tree.map(lambda _: spec, states)
     out_specs = (state_specs,
                  dict(valid=spec, n_valid=spec, cluster=spec, worker=spec,
                       gen_time=spec, reward=spec, agg_count=spec,
                       payload=spec))
-    return shard_map(fn, mesh=mesh,
-                     in_specs=(state_specs,) + (spec,) * 8,
-                     out_specs=out_specs, check_rep=False)(
+    return jax.shard_map(fn, mesh=mesh,
+                         in_specs=(state_specs,) + (spec,) * 8,
+                         out_specs=out_specs, check_vma=False)(
         states, clusters, workers, gen_times, rewards, payloads, thr, send,
         cap)

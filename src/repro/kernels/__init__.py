@@ -5,7 +5,7 @@
                      matmul; per-update integer aggregation weights; fused
                      slot counts; optional multi-queue axis)
   olaf_enqueue     — fused burst enqueue: Algorithm 1 gating as an
-                     in-kernel scalar resolve over SMEM prefetch operands
+                     in-kernel resolve over SMEM per-update scalars
                      plus the telescoped-mean payload matmul, one launch
                      per burst (oracle: olaf_queue.jax_enqueue_burst)
   olaf_step        — the fused full-cycle data plane: burst resolve (with
@@ -19,7 +19,7 @@
   decode_attention — single-token GQA attention streaming a (possibly
                      sequence-sharded) KV cache
 
-ops.py exposes jit'd wrappers (interpret mode on CPU; compiled on TPU via
-REPRO_PALLAS_COMPILED=1); ref.py holds the pure-jnp oracles the test sweep
-asserts against.
+ops.py exposes jit'd wrappers (compiled on a TPU backend, interpreted on
+any other); ref.py holds the pure-jnp oracles the test sweep asserts
+against.
 """

@@ -68,7 +68,7 @@ def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
 def decode_attention_pallas(q: jnp.ndarray, k_cache: jnp.ndarray,
                             v_cache: jnp.ndarray, pos: jnp.ndarray, *,
                             block_s: int = DEFAULT_BLOCK_S,
-                            interpret: bool = True) -> jnp.ndarray:
+                            interpret: bool) -> jnp.ndarray:
     """q: (B, KV, rep, Dh); k/v_cache: (B, S, KV, Dh); pos: (B,) int32.
 
     Returns (B, KV, rep, Dh). Cache entries at positions > pos are masked.

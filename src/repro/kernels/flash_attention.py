@@ -85,7 +85,7 @@ def flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                            q_offset: int = 0,
                            block_q: int = DEFAULT_BLOCK_Q,
                            block_k: int = DEFAULT_BLOCK_K,
-                           interpret: bool = True) -> jnp.ndarray:
+                           interpret: bool) -> jnp.ndarray:
     """q: (BH, Sq, Dh); k/v: (BH, Sk, Dh) — heads pre-folded into batch.
 
     Returns (BH, Sq, Dh). The ops.py wrapper handles the (B,S,H,D) <->

@@ -15,15 +15,22 @@ renormalization — the same arithmetic as Algorithm 1 applied to a burst
 
 The masked segment-sum is expressed as a one-hot (Qt, U) × (U, Dt) matmul so
 it runs on the MXU — there is no per-update unroll, so U scales to hundreds
-of updates with a constant trace size. Tiling: grid over (queues × Q-tiles
-× D-tiles); per step the kernel holds one (U, Dt) update tile and one
-(Qt, Dt) slot tile in VMEM, while ``clusters``/``gate``/``counts`` ride in
-SMEM as scalar-prefetch operands. The kernel is HBM-bandwidth bound by
-design (it must touch every incoming byte exactly once, like the line-rate
-queue); the matmul FLOPs (2·Q·U·D) are far below the MXU roofline at these
-shapes. Updated slot counts are produced by the same kernel launch, and a
-leading S axis batches independent queues (SW1/SW2/SW3-style multi-switch
-combines) in one launch.
+of updates with a constant trace size. The matmul runs at
+``Precision.HIGHEST``: the one-hot weights are small integers, so every
+product is exact and the sum is an f32 sum, never a bf16 pass. Tiling: grid
+over (queues × Q-tiles × D-tiles); per step the kernel holds one (U, Dt)
+update tile and one (Qt, Dt) slot tile in VMEM, beside the (1, U)
+``clusters``/``gate`` rows and the (Qt, 1) ``counts`` column. The kernel is
+HBM-bandwidth bound by design (it must touch every incoming byte exactly
+once, like the line-rate queue); the matmul FLOPs (2·Q·U·D) are far below
+the MXU roofline at these shapes. Updated slot counts are produced by the
+same kernel launch, and a leading S axis batches independent queues
+(SW1/SW2/SW3-style multi-switch combines) in one launch.
+
+Tiles follow the TPU's (8, 128) layout: a Q-tile is a multiple of 8 rows or
+the whole queue, a D-tile a multiple of 128 lanes or the whole row. A D that
+no tile divides leaves a partial last D-block; every column is computed on
+its own, so the block's out-of-range lanes never reach a stored value.
 """
 from __future__ import annotations
 
@@ -32,83 +39,82 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pltpu is importable on CPU too; guard for exotic builds
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
-
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_TILE_D = 512
 DEFAULT_TILE_Q = 8
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _combine_kernel(cluster_ref, gate_ref, count_ref, updates_ref, slots_ref,
                     out_ref, counts_out_ref, *, tile_q: int):
     """One (queue s, Q-tile i, D-tile j) grid step.
 
-    cluster_ref: (S, U) int32 SMEM (scalar prefetch) — cluster id per update
-    gate_ref:    (S, U) int32 SMEM — aggregation weight per update: 0 drops
+    cluster_ref: (1, 1, U) int32 — cluster id per update
+    gate_ref:    (1, 1, U) int32 — aggregation weight per update: 0 drops
                  it, 1 is a plain (un-aggregated) update, w > 1 means the
                  update is itself the mean of w raw updates (a combined
                  packet arriving from an upstream switch) and contributes
                  with weight w — so multi-hop combining stays an exact
                  weighted mean of the raw gradients
-    count_ref:   (S, Q) int32 SMEM — current agg_count per slot
-    updates_ref: (1, U, Dt) VMEM tile of incoming payloads
-    slots_ref:   (1, Qt, Dt) VMEM tile of the current slot payloads
-    out_ref:     (1, Qt, Dt) VMEM tile of the combined slot payloads
+    count_ref:   (1, Qt, 1) int32 — current agg_count per slot
+    updates_ref: (1, U, Dt) tile of incoming payloads
+    slots_ref:   (1, Qt, Dt) tile of the current slot payloads
+    out_ref:     (1, Qt, Dt) tile of the combined slot payloads
     counts_out_ref: (1, Qt, 1) int32 — written once per Q-tile (at j == 0)
     """
-    s, i = pl.program_id(0), pl.program_id(1)
+    i = pl.program_id(1)
     U = updates_ref.shape[1]
-    clusters = cluster_ref[s, :]  # (U,) scalar-prefetch reads
-    gatev = gate_ref[s, :]
-    counts = count_ref[s, pl.ds(i * tile_q, tile_q)]  # (Qt,)
+    counts = count_ref[0]  # (Qt, 1)
 
-    # weighted one-hot membership (Qt, U): 2-D iota (TPU requires >= 2-D
-    # iota); each entry is the update's aggregation weight, not just 1.
+    # weighted one-hot membership (Qt, U): each entry is the update's
+    # aggregation weight, not just 1
     qids = i * tile_q + jax.lax.broadcasted_iota(jnp.int32, (tile_q, U), 0)
-    onehot = jnp.where(clusters[None, :] == qids,
-                       gatev[None, :], 0).astype(jnp.float32)
-    hits = onehot.sum(axis=1).astype(jnp.int32)  # (Qt,)
+    onehot = jnp.where(cluster_ref[0] == qids, gate_ref[0],
+                       0).astype(jnp.float32)
+    hits = onehot.sum(axis=1, keepdims=True).astype(jnp.int32)  # (Qt, 1)
 
-    acc = slots_ref[0].astype(jnp.float32) * counts.astype(jnp.float32)[:, None]
+    acc = slots_ref[0].astype(jnp.float32) * counts.astype(jnp.float32)
     # masked segment-sum as an MXU matmul: (Qt, U) x (U, Dt)
     acc += jnp.dot(onehot, updates_ref[0].astype(jnp.float32),
-                   preferred_element_type=jnp.float32)
+                   preferred_element_type=jnp.float32, precision=_HIGHEST)
     denom = jnp.maximum(counts + hits, 1).astype(jnp.float32)
-    out_ref[0] = (acc / denom[:, None]).astype(out_ref.dtype)
+    out_ref[0] = (acc / denom).astype(out_ref.dtype)
 
     @pl.when(pl.program_id(2) == 0)
     def _():
-        counts_out_ref[0] = (counts + hits)[:, None]
+        counts_out_ref[0] = counts + hits
 
 
 def _pick_tile_q(Q: int, tile_q: int) -> int:
-    tile_q = min(tile_q, Q)
-    while Q % tile_q:
-        tile_q -= 1
-    return tile_q
+    """Largest multiple of 8 that divides ``Q`` and is at most ``tile_q``;
+    the whole queue when there is none (a block spanning the full axis is
+    always a legal TPU tile)."""
+    for t in range(min(tile_q, Q) // 8 * 8, 0, -8):
+        if Q % t == 0:
+            return t
+    return Q
+
+
+def _pick_tile_d(D: int, tile_d: int) -> int:
+    """A multiple of 128 lanes, at most ``tile_d`` (at least 128), or the
+    whole row when it is no wider than that."""
+    tile_d = max(128, tile_d // 128 * 128)
+    return D if D <= tile_d else tile_d
 
 
 def olaf_combine_pallas(slots: jnp.ndarray, counts: jnp.ndarray,
                         updates: jnp.ndarray, clusters: jnp.ndarray,
                         gate: jnp.ndarray, *, tile_q: int = DEFAULT_TILE_Q,
-                        tile_d: int = DEFAULT_TILE_D,
-                        interpret: bool = True):
+                        tile_d: int = DEFAULT_TILE_D, interpret: bool):
     """Fused burst combine; returns ``(new_slots, new_counts)``.
 
     Rank-2: slots (Q, D), counts (Q,), updates (U, D), clusters/gate (U,).
     Rank-3 (multi-queue): a leading S axis on every operand batches S
     independent queues (one per switch) in a single kernel launch.
-    ``interpret=True`` runs the kernel body on CPU (this container); on TPU
-    pass ``interpret=False``.
+    ``interpret=True`` runs the kernel body through the Pallas interpreter
+    (any backend); ``False`` compiles it with Mosaic for a TPU.
     """
-    if pltpu is None:
-        raise ImportError("olaf_combine needs jax.experimental.pallas.tpu "
-                          "(PrefetchScalarGridSpec) — unavailable in this "
-                          "jax build")
     squeeze = slots.ndim == 2
     if squeeze:
         slots, counts = slots[None], counts[None]
@@ -116,32 +122,31 @@ def olaf_combine_pallas(slots: jnp.ndarray, counts: jnp.ndarray,
     S, Q, D = slots.shape
     U = updates.shape[1]
     tile_q = _pick_tile_q(Q, tile_q)
-    tile_d = min(tile_d, D)
-    assert D % tile_d == 0, (D, tile_d)
+    tile_d = _pick_tile_d(D, tile_d)
 
-    grid = (S, Q // tile_q, D // tile_d)
+    grid = (S, Q // tile_q, pl.cdiv(D, tile_d))
     kernel = functools.partial(_combine_kernel, tile_q=tile_q)
+    row = pl.BlockSpec((1, 1, U), lambda s, i, j: (s, 0, 0))
+    col = pl.BlockSpec((1, tile_q, 1), lambda s, i, j: (s, i, 0))
     new_slots, new_counts = pl.pallas_call(
         kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,  # clusters, gate, counts -> SMEM
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, U, tile_d), lambda s, i, j, *prefetch: (s, 0, j)),
-                pl.BlockSpec((1, tile_q, tile_d), lambda s, i, j, *prefetch: (s, i, j)),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, tile_q, tile_d), lambda s, i, j, *prefetch: (s, i, j)),
-                pl.BlockSpec((1, tile_q, 1), lambda s, i, j, *prefetch: (s, i, 0)),
-            ],
-        ),
+        grid=grid,
+        in_specs=[
+            row, row, col,
+            pl.BlockSpec((1, U, tile_d), lambda s, i, j: (s, 0, j)),
+            pl.BlockSpec((1, tile_q, tile_d), lambda s, i, j: (s, i, j)),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, tile_q, tile_d), lambda s, i, j: (s, i, j)),
+            col,
+        ],
         out_shape=[
             jax.ShapeDtypeStruct((S, Q, D), slots.dtype),
             jax.ShapeDtypeStruct((S, Q, 1), jnp.int32),
         ],
         interpret=interpret,
-    )(clusters.astype(jnp.int32), gate.astype(jnp.int32),
-      counts.astype(jnp.int32), updates, slots)
+    )(clusters.astype(jnp.int32)[:, None, :], gate.astype(jnp.int32)[:, None, :],
+      counts.astype(jnp.int32)[..., None], updates, slots)
     new_counts = new_counts[..., 0]
     if squeeze:
         new_slots, new_counts = new_slots[0], new_counts[0]
@@ -159,15 +164,16 @@ _EV_RESET = 2
 
 
 def alg1_resolve(cl0, wk0, sq0, gt0, rw0, cnt0, rp0, nseq0, nd0, na0, nr0,
-                 ns0, thr, U, read_update, qidx, uidx, cap=None):
+                 ns0, thr, U, read_update, cap):
     """In-kernel Algorithm 1 scalar resolve over a U-update burst.
 
     The same sequential walk as ``olaf_queue._burst_resolve``, written to
-    lower on the TPU VPU: a ``fori_loop`` over U carrying only (Q,) metadata
-    vectors, with masked sums in place of dynamic gathers and min-index in
-    place of argmax. Shared by the fused ``olaf_enqueue`` and the full-cycle
-    ``olaf_step`` kernels (``repro.kernels.olaf_step``), which differ only
-    in where the burst scalars come from and what runs after the resolve.
+    lower on the TPU VPU: a ``fori_loop`` over U carrying only (1, Q)
+    metadata rows, with masked sums in place of dynamic gathers and
+    min-index in place of argmax. Shared by the fused ``olaf_enqueue`` and
+    the full-cycle ``olaf_step`` kernels (``repro.kernels.olaf_step``),
+    which differ only in where the burst scalars come from and what runs
+    after the resolve.
 
     ``read_update(u) -> (cluster, worker, gen_time, reward, send, screen)``
     reads one update's scalars (typically from SMEM scalar-prefetch refs);
@@ -179,17 +185,21 @@ def alg1_resolve(cl0, wk0, sq0, gt0, rw0, cnt0, rp0, nseq0, nd0, na0, nr0,
     rejected fraction.
 
     Returns ``(cl, wk, sq, gt, rw, cnt, rp, nseq, nd, na, nr, ns, slots_v,
-    events_v, contributes, last_reset)``: the post-burst metadata columns
-    and counters, the per-update slot/event assignment, and the
-    telescoped-mean bookkeeping consumed by the payload pass.
+    events_v, contributes, last_reset)``: the post-burst (1, Q) metadata
+    rows and counters, the per-update (1, U) slot/event assignment, and
+    the telescoped-mean bookkeeping consumed by the payload pass — a
+    (1, U) contributes row and a (Q, 1) last-reset column, the layouts
+    :func:`_burst_payload_tile` broadcasts against.
 
-    ``cap`` (scalar, default the buffer size Q) is the queue's *logical*
-    slot count: slots at index >= cap never host an append, so one padded
-    (Qmax,) buffer batches switches with heterogeneous per-switch slot
-    vectors (``TopologySpec.queue_slots``) in a single launch.
+    ``cap`` (scalar) is the queue's *logical* slot count: slots at index
+    >= cap never host an append, so one padded (Qmax,) buffer batches
+    switches with heterogeneous per-switch slot vectors
+    (``TopologySpec.queue_slots``) in a single launch.
     """
-    Q = qidx.shape[0]
-    valid_slot = qidx < (Q if cap is None else cap)
+    Q = cl0.shape[1]
+    qidx = jax.lax.broadcasted_iota(jnp.int32, (1, Q), 1)
+    uidx = jax.lax.broadcasted_iota(jnp.int32, (1, U), 1)
+    valid_slot = qidx < cap
 
     def body(u, carry):
         (cl, wk, sq, gt, rw, cnt, rp, nseq, nd, na, nr, ns,
@@ -247,44 +257,77 @@ def alg1_resolve(cl0, wk0, sq0, gt0, rw0, cnt0, rp0, nseq0, nd0, na0, nr0,
         )
 
     carry0 = (cl0, wk0, sq0, gt0, rw0, cnt0, rp0, nseq0, nd0, na0, nr0, ns0,
-              jnp.zeros((U,), jnp.int32), jnp.zeros((U,), jnp.int32))
+              jnp.zeros((1, U), jnp.int32), jnp.zeros((1, U), jnp.int32))
     (cl, wk, sq, gt, rw, cnt, rp, nseq, nd, na, nr, ns,
      slots_v, events_v) = jax.lax.fori_loop(0, U, body, carry0)
 
-    # telescoped-mean bookkeeping: which updates survive into the slot
-    onehot_uq = slots_v[:, None] == qidx[None, :]  # (U, Q)
+    # telescoped-mean bookkeeping: which updates survive into the slot.
+    # Built in the (Q, U) orientation, so the per-slot result comes out a
+    # column and the per-update one a row without a transpose
+    onehot_qu = jax.lax.broadcasted_iota(jnp.int32, (Q, U), 0) == slots_v
     is_reset = events_v == _EV_RESET
     is_agg = events_v == _EV_AGG
-    last_reset = jnp.max(
-        jnp.where(is_reset[:, None] & onehot_uq, uidx[:, None], -1),
-        axis=0)  # (Q,)
-    lr_u = jnp.sum(jnp.where(onehot_uq, last_reset[None, :], 0), axis=1)
+    last_reset = jnp.max(jnp.where(is_reset & onehot_qu, uidx, -1),
+                         axis=1, keepdims=True)  # (Q, 1)
+    lr_u = jnp.sum(jnp.where(onehot_qu, last_reset, 0), axis=0,
+                   keepdims=True)  # (1, U)
     contributes = ((is_agg & (uidx > lr_u))
                    | (is_reset & (uidx == lr_u)))
     return (cl, wk, sq, gt, rw, cnt, rp, nseq, nd, na, nr, ns,
             slots_v, events_v, contributes, last_reset)
 
 
-def _enqueue_kernel(qi_ref, qf_ref, qc_ref, ui_ref, uf_ref,
+def _tile_rows(ref, i, tile_q: int):
+    """Rows ``[i*tile_q, (i+1)*tile_q)`` of a (Q, 1) per-slot column."""
+    if ref.shape[0] == tile_q:
+        return ref[...]
+    return ref[pl.ds(pl.multiple_of(i * tile_q, 8), tile_q), :]
+
+
+def _burst_payload_tile(i, tile_q: int, slots_v, contrib, last_reset,
+                        counts, updates, old):
+    """Telescoped-mean burst combine of one (Qt, Dt) slot tile.
+
+    ``slots_v``/``contrib`` (1, U) are the resolved per-update slot and
+    survival flag, ``last_reset``/``counts`` (Qt, 1) the tile's last reset
+    index and pre-burst agg_count. One one-hot (Qt, U) × (U, Dt)
+    segment-sum on the MXU (exact: 0/1 weights at ``HIGHEST``) plus one
+    blend — ``jax_enqueue_burst``'s payload arithmetic.
+    """
+    U = updates.shape[0]
+    qids = i * tile_q + jax.lax.broadcasted_iota(jnp.int32, (tile_q, U), 0)
+    seg = jnp.where((slots_v == qids) & (contrib != 0), 1.0,
+                    0.0).astype(jnp.float32)  # (Qt, U)
+    sums = jnp.dot(seg, updates.astype(jnp.float32),
+                   preferred_element_type=jnp.float32, precision=_HIGHEST)
+    n_contrib = seg.sum(axis=1, keepdims=True)
+    base_n = jnp.where(last_reset < 0, counts, 0).astype(jnp.float32)
+    touched = (last_reset >= 0) | (n_contrib > 0)
+    denom = jnp.maximum(base_n + n_contrib, 1.0)
+    old = old.astype(jnp.float32)
+    return jnp.where(touched, (old * base_n + sums) / denom, old)
+
+
+def _enqueue_kernel(qc_ref, ui_ref, uf_ref, qi_ref, qf_ref, cnt_ref,
                     updates_ref, slotpay_ref,
                     out_ref, meta_i_ref, meta_f_ref,
                     slots_scr, contrib_scr, lastreset_scr, *, tile_q: int):
     """One (D-tile j, Q-tile i) grid step of the fused burst enqueue.
 
     Scalar-prefetch SMEM operands:
-      qi_ref: (5, Q) int32 — queue [cluster, worker, seq, agg_count, replaceable]
-      qf_ref: (2, Q) f32   — queue [gen_time, reward]
       qc_ref: (1, 6) int32 — [next_seq, n_dropped, n_agg, n_repl, capacity,
                  n_screened] (capacity = the logical slot count; Q when not
                  capped)
       ui_ref: (3, U) int32 — burst [clusters, workers, screen]
       uf_ref: (3, U) f32   — burst [gen_times, rewards, reward_threshold row]
-    VMEM tiles: updates (U, Dt), slotpay (Qt, Dt).
+    VMEM blocks: qi (5, Q) int32 queue [cluster, worker, seq, agg_count,
+    replaceable]; qf (2, Q) f32 queue [gen_time, reward]; cnt (Qt, 1) the
+    tile's agg_count column; updates (U, Dt); slotpay (Qt, Dt).
     Outputs: new payload tile (Qt, Dt); meta_i (10, Q) int32 (rows 0-4 the
     qi columns, rows 5-9 the counters broadcast across Q); meta_f (2, Q)
     f32.
-    SMEM scratch: per-update slot / contributes (1, U) and per-slot
-    last-reset index (1, Q), written once at the first grid step and reused
+    VMEM scratch: per-update slot / contributes (1, U) and per-slot
+    last-reset index (Q, 1), written once at the first grid step and reused
     by every later (j, i) step — TPU grid steps run sequentially on one
     core, so scratch persists across the whole grid. The grid iterates
     D-tiles outermost (Q-tiles innermost), the shared order of the
@@ -292,14 +335,11 @@ def _enqueue_kernel(qi_ref, qf_ref, qc_ref, ui_ref, uf_ref,
     every Q-tile of one D-tile visited consecutively.
 
     The scalar resolve is the shared :func:`alg1_resolve` walk; the payload
-    movement is the telescoped weighted mean of ``jax_enqueue_burst``: one
-    one-hot (Qt, U) × (U, Dt) segment-sum on the MXU plus one blend.
+    movement is :func:`_burst_payload_tile`.
     """
     j, i = pl.program_id(0), pl.program_id(1)
     Q = qi_ref.shape[1]
     U = ui_ref.shape[1]
-    qidx = jax.lax.broadcasted_iota(jnp.int32, (1, Q), 1)[0]
-    uidx = jax.lax.broadcasted_iota(jnp.int32, (1, U), 1)[0]
 
     @pl.when((i == 0) & (j == 0))
     def _resolve():
@@ -308,49 +348,28 @@ def _enqueue_kernel(qi_ref, qf_ref, qc_ref, ui_ref, uf_ref,
                     jnp.bool_(True), ui_ref[2, u] != 0)
 
         (cl, wk, sq, gt, rw, cnt, rp, nseq, nd, na, nr, ns,
-         slots_v, events_v, contributes, last_reset) = alg1_resolve(
-            qi_ref[0, :], qi_ref[1, :], qi_ref[2, :], qf_ref[0, :],
-            qf_ref[1, :], qi_ref[3, :], qi_ref[4, :],
+         slots_v, _, contributes, last_reset) = alg1_resolve(
+            qi_ref[0:1, :], qi_ref[1:2, :], qi_ref[2:3, :], qf_ref[0:1, :],
+            qf_ref[1:2, :], qi_ref[3:4, :], qi_ref[4:5, :],
             qc_ref[0, 0], qc_ref[0, 1], qc_ref[0, 2], qc_ref[0, 3],
-            qc_ref[0, 5],
-            uf_ref[2, 0], U, read_update, qidx, uidx, cap=qc_ref[0, 4])
+            qc_ref[0, 5], uf_ref[2, 0], U, read_update, cap=qc_ref[0, 4])
 
-        slots_scr[0, :] = slots_v
-        contrib_scr[0, :] = contributes.astype(jnp.int32)
-        lastreset_scr[0, :] = last_reset
+        slots_scr[...] = slots_v
+        contrib_scr[...] = contributes.astype(jnp.int32)
+        lastreset_scr[...] = last_reset
 
-        meta_i_ref[0, :] = cl
-        meta_i_ref[1, :] = wk
-        meta_i_ref[2, :] = sq
-        meta_i_ref[3, :] = cnt
-        meta_i_ref[4, :] = rp
-        meta_i_ref[5, :] = jnp.zeros((Q,), jnp.int32) + nseq
-        meta_i_ref[6, :] = jnp.zeros((Q,), jnp.int32) + nd
-        meta_i_ref[7, :] = jnp.zeros((Q,), jnp.int32) + na
-        meta_i_ref[8, :] = jnp.zeros((Q,), jnp.int32) + nr
-        meta_i_ref[9, :] = jnp.zeros((Q,), jnp.int32) + ns
-        meta_f_ref[0, :] = gt
-        meta_f_ref[1, :] = rw
+        zero = jnp.zeros((1, Q), jnp.int32)
+        for r, v in enumerate((cl, wk, sq, cnt, rp, zero + nseq, zero + nd,
+                               zero + na, zero + nr, zero + ns)):
+            meta_i_ref[r:r + 1, :] = v
+        meta_f_ref[0:1, :] = gt
+        meta_f_ref[1:2, :] = rw
 
     # ---- payload pass (every grid step, MXU) ----------------------------
-    slots_v = slots_scr[0, :]
-    contrib = contrib_scr[0, :]
-    lr_tile = lastreset_scr[0, pl.ds(i * tile_q, tile_q)]
-    counts_tile = qi_ref[3, pl.ds(i * tile_q, tile_q)]  # pre-burst agg_count
-    qids = i * tile_q + jax.lax.broadcasted_iota(
-        jnp.int32, (tile_q, updates_ref.shape[0]), 0)
-    seg = jnp.where((slots_v[None, :] == qids) & (contrib[None, :] != 0),
-                    1.0, 0.0).astype(jnp.float32)  # (Qt, U)
-    sums = jnp.dot(seg, updates_ref[...].astype(jnp.float32),
-                   preferred_element_type=jnp.float32)
-    n_contrib = seg.sum(axis=1)
-    base_n = jnp.where(lr_tile < 0, counts_tile, 0).astype(jnp.float32)
-    touched = (lr_tile >= 0) | (n_contrib > 0)
-    denom = jnp.maximum(base_n + n_contrib, 1.0)
-    old = slotpay_ref[...].astype(jnp.float32)
-    combined = (old * base_n[:, None] + sums) / denom[:, None]
-    out_ref[...] = jnp.where(touched[:, None], combined,
-                             old).astype(out_ref.dtype)
+    out_ref[...] = _burst_payload_tile(
+        i, tile_q, slots_scr[...], contrib_scr[...],
+        _tile_rows(lastreset_scr, i, tile_q), cnt_ref[...], updates_ref[...],
+        slotpay_ref[...]).astype(out_ref.dtype)
 
 
 def olaf_enqueue_pallas(cluster, worker, seq, gen_time, reward, agg_count,
@@ -359,22 +378,17 @@ def olaf_enqueue_pallas(cluster, worker, seq, gen_time, reward, agg_count,
                         payloads, reward_threshold=float("inf"),
                         capacity=None, n_screened=0, screen=None, *,
                         tile_q: int = DEFAULT_TILE_Q,
-                        tile_d: int = DEFAULT_TILE_D,
-                        interpret: bool = True):
+                        tile_d: int = DEFAULT_TILE_D, interpret: bool):
     """Single-launch fused burst enqueue over raw queue-state arrays.
 
     Returns ``(new_payload (Q, D), meta_i (10, Q) int32, meta_f (2, Q)
     f32)`` — see :func:`_enqueue_kernel` for the packing. The
     JaxQueueState-typed wrapper lives in ``repro.kernels.ops.olaf_enqueue``.
     """
-    if pltpu is None:
-        raise ImportError("olaf_enqueue needs jax.experimental.pallas.tpu "
-                          "(PrefetchScalarGridSpec) — unavailable in this "
-                          "jax build")
     Q, D = payload.shape
     U = clusters.shape[0]
     tile_q = _pick_tile_q(Q, tile_q)
-    tile_d = _pick_tile_q(D, tile_d)  # same largest-divisor shrink for D
+    tile_d = _pick_tile_d(D, tile_d)
     i32, f32 = jnp.int32, jnp.float32
     if capacity is None:
         capacity = Q
@@ -392,26 +406,29 @@ def olaf_enqueue_pallas(cluster, worker, seq, gen_time, reward, agg_count,
     uf = jnp.stack([gen_times.astype(f32), rewards.astype(f32),
                     jnp.full((U,), reward_threshold, f32)])
 
-    grid = (D // tile_d, Q // tile_q)  # D-tiles outer, Q-tiles inner
+    grid = (pl.cdiv(D, tile_d), Q // tile_q)  # D-tiles outer, Q-tiles inner
     kernel = functools.partial(_enqueue_kernel, tile_q=tile_q)
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=5,  # qi, qf, qc, ui, uf -> SMEM
+            num_scalar_prefetch=3,  # qc, ui, uf -> SMEM
             grid=grid,
             in_specs=[
-                pl.BlockSpec((U, tile_d), lambda j, i, *prefetch: (0, j)),
-                pl.BlockSpec((tile_q, tile_d), lambda j, i, *prefetch: (i, j)),
+                pl.BlockSpec((5, Q), lambda j, i, *p: (0, 0)),
+                pl.BlockSpec((2, Q), lambda j, i, *p: (0, 0)),
+                pl.BlockSpec((tile_q, 1), lambda j, i, *p: (i, 0)),
+                pl.BlockSpec((U, tile_d), lambda j, i, *p: (0, j)),
+                pl.BlockSpec((tile_q, tile_d), lambda j, i, *p: (i, j)),
             ],
             out_specs=[
-                pl.BlockSpec((tile_q, tile_d), lambda j, i, *prefetch: (i, j)),
-                pl.BlockSpec((10, Q), lambda j, i, *prefetch: (0, 0)),
-                pl.BlockSpec((2, Q), lambda j, i, *prefetch: (0, 0)),
+                pl.BlockSpec((tile_q, tile_d), lambda j, i, *p: (i, j)),
+                pl.BlockSpec((10, Q), lambda j, i, *p: (0, 0)),
+                pl.BlockSpec((2, Q), lambda j, i, *p: (0, 0)),
             ],
             scratch_shapes=[
-                pltpu.SMEM((1, U), jnp.int32),
-                pltpu.SMEM((1, U), jnp.int32),
-                pltpu.SMEM((1, Q), jnp.int32),
+                pltpu.VMEM((1, U), jnp.int32),  # resolved slot per update
+                pltpu.VMEM((1, U), jnp.int32),  # contributes per update
+                pltpu.VMEM((Q, 1), jnp.int32),  # last reset per slot
             ],
         ),
         out_shape=[
@@ -420,4 +437,4 @@ def olaf_enqueue_pallas(cluster, worker, seq, gen_time, reward, agg_count,
             jax.ShapeDtypeStruct((2, Q), jnp.float32),
         ],
         interpret=interpret,
-    )(qi, qf, qc, ui, uf, payloads, payload)
+    )(qc, ui, uf, qi, qf, agg_count.astype(i32)[:, None], payloads, payload)

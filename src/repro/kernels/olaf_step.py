@@ -5,16 +5,16 @@ top-k pass:
 
   1. **burst-enqueue scalar resolve** — Algorithm 1 gating for a U-update
      incast burst, the shared :func:`repro.kernels.olaf_combine.alg1_resolve`
-     fori_loop over SMEM scalar-prefetch operands, run once at the first
-     grid step. An optional per-update ``send`` gate (worker-side
+     fori_loop over SMEM per-update scalars and (1, Q) VMEM metadata rows,
+     run once at the first grid step. An optional per-update ``send`` gate (worker-side
      transmission control, §5) defers masked-out updates without touching
      the queue.
   2. **drain-k oldest-valid selection** — the k slots with the smallest
      post-enqueue sequence numbers, ties (the empty-slot sentinel) broken by
      slot index, reproducing ``jax.lax.top_k``'s ordering exactly so the
      kernel matches the ``jax_enqueue_burst → jax_dequeue_burst`` oracle
-     row for row. A k-step selection loop over (Q,) SMEM vectors, also at
-     the first grid step.
+     row for row. A k-step selection loop over the (1, Q) rows, also at the
+     first grid step.
   3. **payload combine + gather** — on every (Q-tile × D-tile) grid step:
      the telescoped-mean burst combine (one one-hot (Qt, U) × (U, Dt)
      segment-sum on the MXU plus a blend), then the drained rows gathered
@@ -22,13 +22,15 @@ top-k pass:
      accumulated across Q-tiles, and the popped slots zeroed in the new
      payload output.
 
-SMEM scratch carries the resolved slot/contribute assignment and the drain
-slot/valid selection across grid steps (TPU grid steps run sequentially on
-one core, so scratch written at a switch's first step is visible to all its
+VMEM scratch carries the resolved slot/contribute assignment and the drain
+slot selection across grid steps (TPU grid steps run sequentially on one
+core, so scratch written at a switch's first step is visible to all its
 later steps). The grid iterates (S, D-tiles, Q-tiles) with Q-tiles
 innermost: for a fixed D-tile every Q-tile is visited consecutively, so the
 (K, Dt) drained output block stays resident in VMEM while its cross-Q-tile
-accumulation runs.
+accumulation runs. Only the burst's per-update scalars and the queue
+counters ride in SMEM (scalar prefetch); the per-slot metadata rows live in
+VMEM, where the resolve's vector ops run.
 
 A leading S axis batches independent queues (the SW1/SW2/SW3 multi-switch
 data plane) in one launch; `repro.distributed.sharding.olaf_step_sharded`
@@ -41,36 +43,35 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # pltpu is importable on CPU too; guard for exotic builds
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
-
-from repro.kernels.olaf_combine import _pick_tile_q, alg1_resolve
+from repro.kernels.olaf_combine import (_HIGHEST, _burst_payload_tile,
+                                        _pick_tile_d, _pick_tile_q,
+                                        _tile_rows, alg1_resolve)
 
 _SENTINEL = jnp.iinfo(jnp.int32).max
 _NEG_INF = float("-inf")
 
 
-def _olaf_step_kernel(qi_ref, qf_ref, qc_ref, ui_ref, uf_ref,
+def _olaf_step_kernel(qc_ref, ui_ref, uf_ref, qi_ref, qf_ref, cnt_ref,
                       updates_ref, slotpay_ref,
                       out_ref, drained_ref, meta_i_ref, meta_f_ref,
                       drain_i_ref, drain_f_ref,
                       slots_scr, contrib_scr, lastreset_scr,
-                      dslot_scr, dvalid_scr, *, tile_q: int, k: int):
+                      dslot_scr, popped_scr, *, tile_q: int, k: int):
     """One (queue s, D-tile j, Q-tile i) grid step of the fused cycle.
 
     Scalar-prefetch SMEM operands (leading S axis on all of them):
-      qi_ref: (S, 5, Q) int32 — [cluster, worker, seq, agg_count, replaceable]
-      qf_ref: (S, 2, Q) f32   — [gen_time, reward]
       qc_ref: (S, 1, 6) int32 — [next_seq, n_dropped, n_agg, n_repl,
                  capacity, n_screened] (capacity = the per-switch logical
                  slot count — heterogeneous ``TopologySpec.queue_slots``
                  ride in one padded (S, Qmax) launch; Q when not capped)
       ui_ref: (S, 4, U) int32 — burst [clusters, workers, send, screen]
       uf_ref: (S, 3, U) f32   — burst [gen_times, rewards, threshold row]
-    VMEM tiles: updates (1, U, Dt), slotpay (1, Qt, Dt).
+    VMEM blocks: qi (1, 5, Q) int32 [cluster, worker, seq, agg_count,
+    replaceable]; qf (1, 2, Q) f32 [gen_time, reward]; cnt (1, Qt, 1) the
+    tile's pre-burst agg_count column; updates (1, U, Dt); slotpay
+    (1, Qt, Dt).
     Outputs:
       out_ref     (1, Qt, Dt) — post-enqueue, post-drain slot payload tile
       drained_ref (1, K, Dt)  — drained rows, accumulated across Q-tiles
@@ -80,15 +81,13 @@ def _olaf_step_kernel(qi_ref, qf_ref, qc_ref, ui_ref, uf_ref,
       drain_i_ref (1, 4, K)   — per drained row [cluster, worker,
                                 agg_count, valid], read pre-clear
       drain_f_ref (1, 2, K)   — per drained row [gen_time, reward]
-    SMEM scratch: enqueue resolve (slots/contrib per update, last-reset per
-    slot) and drain selection (slot/valid per drained row).
+    VMEM scratch: enqueue resolve (slot/contributes per update, last reset
+    per slot) and drain selection (drained slot per row, -1 when the row
+    is invalid; popped flag per slot).
     """
     s, j, i = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     Q = qi_ref.shape[2]
     U = ui_ref.shape[2]
-    qidx = jax.lax.broadcasted_iota(jnp.int32, (1, Q), 1)[0]
-    uidx = jax.lax.broadcasted_iota(jnp.int32, (1, U), 1)[0]
-    kidx = jax.lax.broadcasted_iota(jnp.int32, (1, k), 1)[0]
 
     @pl.when((j == 0) & (i == 0))
     def _resolve_and_select():
@@ -98,110 +97,97 @@ def _olaf_step_kernel(qi_ref, qf_ref, qc_ref, ui_ref, uf_ref,
                     uf_ref[s, 1, u], ui_ref[s, 2, u] != 0,
                     ui_ref[s, 3, u] != 0)
 
+        qi, qf = qi_ref[0], qf_ref[0]
         (cl, wk, sq, gt, rw, cnt, rp, nseq, nd, na, nr, ns,
-         slots_v, events_v, contributes, last_reset) = alg1_resolve(
-            qi_ref[s, 0, :], qi_ref[s, 1, :], qi_ref[s, 2, :],
-            qf_ref[s, 0, :], qf_ref[s, 1, :], qi_ref[s, 3, :],
-            qi_ref[s, 4, :],
+         slots_v, _, contributes, last_reset) = alg1_resolve(
+            qi[0:1], qi[1:2], qi[2:3], qf[0:1], qf[1:2], qi[3:4], qi[4:5],
             qc_ref[s, 0, 0], qc_ref[s, 0, 1], qc_ref[s, 0, 2],
             qc_ref[s, 0, 3], qc_ref[s, 0, 5],
-            uf_ref[s, 2, 0], U, read_update, qidx, uidx,
-            cap=qc_ref[s, 0, 4])
+            uf_ref[s, 2, 0], U, read_update, cap=qc_ref[s, 0, 4])
 
-        slots_scr[0, :] = slots_v
-        contrib_scr[0, :] = contributes.astype(jnp.int32)
-        lastreset_scr[0, :] = last_reset
+        slots_scr[...] = slots_v
+        contrib_scr[...] = contributes.astype(jnp.int32)
+        lastreset_scr[...] = last_reset
 
         # ---- 2. drain-k oldest-valid selection --------------------------
         # k smallest post-enqueue seqs, sentinel ties broken by slot index:
         # the same (value, index) order lax.top_k(-seq) produces, so the
         # drained rows match the two-launch oracle exactly — including the
-        # stale metadata invalid rows read from sentinel slots.
+        # stale metadata invalid rows read from sentinel slots. Each
+        # selected row's metadata is read (pre-clear) by a masked sum.
+        qidx = jax.lax.broadcasted_iota(jnp.int32, (1, Q), 1)
+        kidx = jax.lax.broadcasted_iota(jnp.int32, (1, k), 1)
+        kcol = jax.lax.broadcasted_iota(jnp.int32, (k, 1), 0)
+
         def select(t, carry):
-            taken, dslots, dvalid = carry
+            taken, popped, dcol, dsel, dcl, dwk, dcnt, dval, dgt, drw = carry
             seq_m = jnp.where(taken != 0, _SENTINEL, sq)
             m = jnp.min(seq_m)
             slot = jnp.min(jnp.where((taken == 0) & (seq_m == m), qidx, Q))
-            c_at = jnp.sum(jnp.where(qidx == slot, cl, 0))
-            return (jnp.where(qidx == slot, 1, taken),
-                    jnp.where(kidx == t, slot, dslots),
-                    jnp.where(kidx == t, (c_at >= 0).astype(jnp.int32),
-                              dvalid))
+            at = qidx == slot
+            c_at = jnp.sum(jnp.where(at, cl, 0))
+            valid = c_at >= 0
+            sel = jnp.where(valid, slot, -1)
 
-        taken0 = jnp.zeros((Q,), jnp.int32)
-        _, dslots, dvalid = jax.lax.fori_loop(
-            0, k, select, (taken0, jnp.zeros((k,), jnp.int32),
-                           jnp.zeros((k,), jnp.int32)))
-        dslot_scr[0, :] = dslots
-        dvalid_scr[0, :] = dvalid
+            def put(row, v):
+                return jnp.where(kidx == t, v, row)
 
-        onehot_kq = dslots[:, None] == qidx[None, :]  # (K, Q), unmasked
-        pop_kq = onehot_kq & (dvalid[:, None] != 0)
-        popped = jnp.sum(pop_kq.astype(jnp.int32), axis=0) > 0  # (Q,)
+            return (jnp.where(at, 1, taken),
+                    jnp.where(at & valid, 1, popped),
+                    jnp.where(kcol == t, sel, dcol), put(dsel, sel),
+                    put(dcl, c_at), put(dwk, jnp.sum(jnp.where(at, wk, 0))),
+                    put(dcnt, jnp.sum(jnp.where(at, cnt, 0))),
+                    put(dval, valid.astype(jnp.int32)),
+                    put(dgt, jnp.sum(jnp.where(at, gt, 0.0))),
+                    put(drw, jnp.sum(jnp.where(at, rw, 0.0))))
 
-        def gather_i(vec):  # (Q,) int32 -> (K,) rows, pre-clear values
-            return jnp.sum(jnp.where(onehot_kq, vec[None, :], 0), axis=1)
+        zq = jnp.zeros((1, Q), jnp.int32)
+        zk = jnp.zeros((1, k), jnp.int32)
+        zkf = jnp.zeros((1, k), jnp.float32)
+        (_, popped, dcol, dsel, dcl, dwk, dcnt, dval, dgt,
+         drw) = jax.lax.fori_loop(
+            0, k, select, (zq, zq, jnp.zeros((k, 1), jnp.int32), zk, zk, zk,
+                           zk, zk, zkf, zkf))
+        dslot_scr[...] = dcol
+        popped_scr[...] = jnp.max(
+            jnp.where(jax.lax.broadcasted_iota(jnp.int32, (Q, k), 0) == dsel,
+                      1, 0), axis=1, keepdims=True)  # (Q, 1)
 
-        def gather_f(vec):
-            return jnp.sum(jnp.where(onehot_kq, vec[None, :], 0.0), axis=1)
-
-        drain_i_ref[0, 0, :] = gather_i(cl)
-        drain_i_ref[0, 1, :] = gather_i(wk)
-        drain_i_ref[0, 2, :] = gather_i(cnt)
-        drain_i_ref[0, 3, :] = dvalid
-        drain_f_ref[0, 0, :] = gather_f(gt)
-        drain_f_ref[0, 1, :] = gather_f(rw)
+        for r, v in enumerate((dcl, dwk, dcnt, dval)):
+            drain_i_ref[0, r:r + 1, :] = v
+        drain_f_ref[0, 0:1, :] = dgt
+        drain_f_ref[0, 1:2, :] = drw
 
         # ---- post-drain metadata (popped slots cleared; gen_time kept,
         # matching jax_dequeue_burst) -------------------------------------
-        meta_i_ref[0, 0, :] = jnp.where(popped, -1, cl)
-        meta_i_ref[0, 1, :] = jnp.where(popped, -1, wk)
-        meta_i_ref[0, 2, :] = jnp.where(popped, _SENTINEL, sq)
-        meta_i_ref[0, 3, :] = jnp.where(popped, 0, cnt)
-        meta_i_ref[0, 4, :] = jnp.where(popped, 0, rp)
-        meta_i_ref[0, 5, :] = jnp.zeros((Q,), jnp.int32) + nseq
-        meta_i_ref[0, 6, :] = jnp.zeros((Q,), jnp.int32) + nd
-        meta_i_ref[0, 7, :] = jnp.zeros((Q,), jnp.int32) + na
-        meta_i_ref[0, 8, :] = jnp.zeros((Q,), jnp.int32) + nr
-        meta_i_ref[0, 9, :] = jnp.zeros((Q,), jnp.int32) + ns
-        meta_f_ref[0, 0, :] = gt
-        meta_f_ref[0, 1, :] = jnp.where(popped, _NEG_INF, rw)
+        pop = popped != 0
+        for r, v in enumerate((
+                jnp.where(pop, -1, cl), jnp.where(pop, -1, wk),
+                jnp.where(pop, _SENTINEL, sq), jnp.where(pop, 0, cnt),
+                jnp.where(pop, 0, rp), zq + nseq, zq + nd, zq + na,
+                zq + nr, zq + ns)):
+            meta_i_ref[0, r:r + 1, :] = v
+        meta_f_ref[0, 0:1, :] = gt
+        meta_f_ref[0, 1:2, :] = jnp.where(pop, _NEG_INF, rw)
 
     # ---- 3. payload pass (every grid step, MXU) --------------------------
-    slots_v = slots_scr[0, :]
-    contrib = contrib_scr[0, :]
-    lr_tile = lastreset_scr[0, pl.ds(i * tile_q, tile_q)]
-    counts_tile = qi_ref[s, 3, pl.ds(i * tile_q, tile_q)]  # pre-burst counts
-    U_ = updates_ref.shape[1]
-    qids = i * tile_q + jax.lax.broadcasted_iota(jnp.int32, (tile_q, U_), 0)
-    seg = jnp.where((slots_v[None, :] == qids) & (contrib[None, :] != 0),
-                    1.0, 0.0).astype(jnp.float32)  # (Qt, U)
-    sums = jnp.dot(seg, updates_ref[0].astype(jnp.float32),
-                   preferred_element_type=jnp.float32)
-    n_contrib = seg.sum(axis=1)
-    base_n = jnp.where(lr_tile < 0, counts_tile, 0).astype(jnp.float32)
-    touched = (lr_tile >= 0) | (n_contrib > 0)
-    denom = jnp.maximum(base_n + n_contrib, 1.0)
-    old = slotpay_ref[0].astype(jnp.float32)
-    combined = jnp.where(touched[:, None],
-                         (old * base_n[:, None] + sums) / denom[:, None],
-                         old)  # post-enqueue, pre-drain tile
+    combined = _burst_payload_tile(
+        i, tile_q, slots_scr[...], contrib_scr[...],
+        _tile_rows(lastreset_scr, i, tile_q), cnt_ref[0], updates_ref[0],
+        slotpay_ref[0])  # post-enqueue, pre-drain tile
 
     # drained-row gather from the combined tile: each row selects exactly
-    # one slot, so the cross-tile accumulation is exact (single-term sums)
-    dslots = dslot_scr[0, :]
-    dvalid = dvalid_scr[0, :]
+    # one slot, so the cross-tile accumulation is exact (single-term sums;
+    # HIGHEST keeps the one-hot matmul an f32 copy)
     tile_qids = i * tile_q + jax.lax.broadcasted_iota(
         jnp.int32, (k, tile_q), 1)
-    onehot_k = jnp.where((dslots[:, None] == tile_qids)
-                         & (dvalid[:, None] != 0), 1.0,
+    onehot_k = jnp.where(dslot_scr[...] == tile_qids, 1.0,
                          0.0).astype(jnp.float32)  # (K, Qt)
-    part = jnp.dot(onehot_k, combined,
-                   preferred_element_type=jnp.float32)  # (K, Dt)
-    popped_tile = onehot_k.sum(axis=0) > 0  # (Qt,)
+    part = jnp.dot(onehot_k, combined, preferred_element_type=jnp.float32,
+                   precision=_HIGHEST)  # (K, Dt)
+    popped_tile = _tile_rows(popped_scr, i, tile_q) != 0  # (Qt, 1)
 
-    out_ref[0] = jnp.where(popped_tile[:, None], 0.0,
-                           combined).astype(out_ref.dtype)
+    out_ref[0] = jnp.where(popped_tile, 0.0, combined).astype(out_ref.dtype)
 
     @pl.when(i == 0)
     def _init_drained():
@@ -218,7 +204,7 @@ def olaf_step_pallas(cluster, worker, seq, gen_time, reward, agg_count,
                      payloads, k: int, reward_threshold=float("inf"),
                      send=None, capacity=None, n_screened=0, screen=None,
                      *, tile_q: int = 8, tile_d: int = 512,
-                     interpret: bool = True):
+                     interpret: bool):
     """Single-launch fused enqueue→drain cycle over raw queue-state arrays.
 
     Rank-2 ``payload (Q, D)`` runs one queue; a leading S axis on every
@@ -227,12 +213,10 @@ def olaf_step_pallas(cluster, worker, seq, gen_time, reward, agg_count,
     Returns ``(new_payload, drained_payload (…, K, D), meta_i (…, 10, Q),
     meta_f (…, 2, Q), drain_i (…, 4, K), drain_f (…, 2, K))`` — see
     :func:`_olaf_step_kernel` for the packing. The JaxQueueState-typed
-    wrapper lives in ``repro.kernels.ops.olaf_step``.
+    wrapper lives in ``repro.kernels.ops.olaf_step``. ``interpret=True``
+    runs the kernel body through the Pallas interpreter (any backend);
+    ``False`` compiles it with Mosaic for a TPU.
     """
-    if pltpu is None:
-        raise ImportError("olaf_step needs jax.experimental.pallas.tpu "
-                          "(PrefetchScalarGridSpec) — unavailable in this "
-                          "jax build")
     squeeze = payload.ndim == 2
     if squeeze:
         (cluster, worker, seq, gen_time, reward, agg_count, replaceable,
@@ -251,7 +235,7 @@ def olaf_step_pallas(cluster, worker, seq, gen_time, reward, agg_count,
     U = clusters.shape[1]
     k = min(int(k), Q)
     tile_q = _pick_tile_q(Q, tile_q)
-    tile_d = _pick_tile_q(D, tile_d)  # same largest-divisor shrink for D
+    tile_d = _pick_tile_d(D, tile_d)
     i32, f32 = jnp.int32, jnp.float32
     if send is None:
         send = jnp.ones((S, U), i32)
@@ -271,14 +255,21 @@ def olaf_step_pallas(cluster, worker, seq, gen_time, reward, agg_count,
     uf = jnp.stack([gen_times.astype(f32), rewards.astype(f32),
                     jnp.full((S, U), reward_threshold, f32)], axis=1)
 
-    grid = (S, D // tile_d, Q // tile_q)  # Q-tiles innermost (see module doc)
+    # Q-tiles innermost (see module doc)
+    grid = (S, pl.cdiv(D, tile_d), Q // tile_q)
     kernel = functools.partial(_olaf_step_kernel, tile_q=tile_q, k=k)
+
+    def whole(rows, cols):
+        return pl.BlockSpec((1, rows, cols), lambda s, j, i, *p: (s, 0, 0))
+
     outs = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=5,  # qi, qf, qc, ui, uf -> SMEM
+            num_scalar_prefetch=3,  # qc, ui, uf -> SMEM
             grid=grid,
             in_specs=[
+                whole(5, Q), whole(2, Q),
+                pl.BlockSpec((1, tile_q, 1), lambda s, j, i, *p: (s, i, 0)),
                 pl.BlockSpec((1, U, tile_d), lambda s, j, i, *p: (s, 0, j)),
                 pl.BlockSpec((1, tile_q, tile_d),
                              lambda s, j, i, *p: (s, i, j)),
@@ -287,17 +278,14 @@ def olaf_step_pallas(cluster, worker, seq, gen_time, reward, agg_count,
                 pl.BlockSpec((1, tile_q, tile_d),
                              lambda s, j, i, *p: (s, i, j)),
                 pl.BlockSpec((1, k, tile_d), lambda s, j, i, *p: (s, 0, j)),
-                pl.BlockSpec((1, 10, Q), lambda s, j, i, *p: (s, 0, 0)),
-                pl.BlockSpec((1, 2, Q), lambda s, j, i, *p: (s, 0, 0)),
-                pl.BlockSpec((1, 4, k), lambda s, j, i, *p: (s, 0, 0)),
-                pl.BlockSpec((1, 2, k), lambda s, j, i, *p: (s, 0, 0)),
+                whole(10, Q), whole(2, Q), whole(4, k), whole(2, k),
             ],
             scratch_shapes=[
-                pltpu.SMEM((1, U), jnp.int32),  # resolved slot per update
-                pltpu.SMEM((1, U), jnp.int32),  # contributes per update
-                pltpu.SMEM((1, Q), jnp.int32),  # last reset per slot
-                pltpu.SMEM((1, k), jnp.int32),  # drained slot per row
-                pltpu.SMEM((1, k), jnp.int32),  # drained validity per row
+                pltpu.VMEM((1, U), jnp.int32),  # resolved slot per update
+                pltpu.VMEM((1, U), jnp.int32),  # contributes per update
+                pltpu.VMEM((Q, 1), jnp.int32),  # last reset per slot
+                pltpu.VMEM((k, 1), jnp.int32),  # drained slot per row
+                pltpu.VMEM((Q, 1), jnp.int32),  # popped flag per slot
             ],
         ),
         out_shape=[
@@ -309,7 +297,8 @@ def olaf_step_pallas(cluster, worker, seq, gen_time, reward, agg_count,
             jax.ShapeDtypeStruct((S, 2, k), jnp.float32),
         ],
         interpret=interpret,
-    )(qi, qf, qc, ui, uf, payloads, payload)
+    )(qc, ui, uf, qi, qf, agg_count.astype(i32)[..., None], payloads,
+      payload)
     if squeeze:
         outs = [o[0] for o in outs]
     return tuple(outs)

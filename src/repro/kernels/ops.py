@@ -1,13 +1,15 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-``interpret`` defaults to True in this CPU container (the kernel bodies run
-through the Pallas interpreter); on a real TPU pass ``interpret=False`` (or
-set REPRO_PALLAS_COMPILED=1) to compile them to Mosaic.
+Whether a kernel is compiled is read from the backend at trace time: on a
+TPU every kernel compiles with Mosaic; on any other backend the kernel
+bodies run through the Pallas interpreter. No flag or environment variable
+changes that. The raw ``*_pallas`` functions keep an explicit
+``interpret`` argument, so a test can compile them for a described TPU
+while the CPU is the backend.
 """
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -19,12 +21,15 @@ from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.olaf_combine import olaf_combine_pallas, olaf_enqueue_pallas
 from repro.kernels.olaf_step import olaf_step_pallas
 
-_INTERPRET = os.environ.get("REPRO_PALLAS_COMPILED", "0") != "1"
+
+def _interpret() -> bool:
+    """Pallas kernels are compiled on a TPU and interpreted elsewhere."""
+    return jax.default_backend() != "tpu"
 
 
-@functools.partial(jax.jit, static_argnames=("tile_q", "tile_d", "interpret"))
+@functools.partial(jax.jit, static_argnames=("tile_q", "tile_d"))
 def olaf_combine(slots, counts, updates, clusters, gate, *, tile_q: int = 8,
-                 tile_d: int = 512, interpret: bool = _INTERPRET):
+                 tile_d: int = 512):
     """Combine a burst of updates into cluster slots (running mean).
 
     slots (Q,D), counts (Q,) int32, updates (U,D), clusters (U,) int32,
@@ -39,12 +44,11 @@ def olaf_combine(slots, counts, updates, clusters, gate, *, tile_q: int = 8,
     gate = gate.astype(jnp.int32)
     return olaf_combine_pallas(slots, counts, updates, clusters, gate,
                                tile_q=tile_q, tile_d=tile_d,
-                               interpret=interpret)
+                               interpret=_interpret())
 
 
 def olaf_combine_multi(slots, counts, updates, clusters, gate, *,
-                       tile_q: int = 8, tile_d: int = 512,
-                       interpret: bool = _INTERPRET):
+                       tile_q: int = 8, tile_d: int = 512):
     """Multi-queue combine: every operand carries a leading S (switch) axis.
 
     slots (S,Q,D), counts (S,Q), updates (S,U,D), clusters/gate (S,U)
@@ -53,12 +57,11 @@ def olaf_combine_multi(slots, counts, updates, clusters, gate, *,
     axis folded into the Pallas grid.
     """
     return olaf_combine(slots, counts, updates, clusters, gate,
-                        tile_q=tile_q, tile_d=tile_d, interpret=interpret)
+                        tile_q=tile_q, tile_d=tile_d)
 
 
 def olaf_combine_window(slots, counts, updates, clusters, gate, reset_slots,
-                        *, tile_q: int = 8, tile_d: int = 512,
-                        interpret: bool = _INTERPRET):
+                        *, tile_q: int = 8, tile_d: int = 512):
     """Window-batched gate entry for the hybrid control-plane replay.
 
     Lands one whole transmission window — ``updates`` (S, U, D) staged as a
@@ -73,14 +76,13 @@ def olaf_combine_window(slots, counts, updates, clusters, gate, reset_slots,
     counts_in = jnp.where(jnp.asarray(reset_slots), 0, counts)
     return olaf_combine_multi(slots, counts_in, updates,
                               jnp.asarray(clusters), jnp.asarray(gate),
-                              tile_q=tile_q, tile_d=tile_d,
-                              interpret=interpret)
+                              tile_q=tile_q, tile_d=tile_d)
 
 
-@functools.partial(jax.jit, static_argnames=("tile_q", "tile_d", "interpret"))
+@functools.partial(jax.jit, static_argnames=("tile_q", "tile_d"))
 def olaf_forward(slots, counts, updates, clusters, gate, reset_slots,
                  drain_sw, drain_slot, drain_hop=None, *, tile_q: int = 8,
-                 tile_d: int = 512, interpret: bool = _INTERPRET):
+                 tile_d: int = 512):
     """Window combine + device-resident forwarding pass, one dispatch.
 
     First lands the pending transmission window (exactly
@@ -106,7 +108,7 @@ def olaf_forward(slots, counts, updates, clusters, gate, reset_slots,
     if updates.shape[1] > 0:
         slots, counts = olaf_combine_window(
             slots, counts, updates, clusters, gate, reset_slots,
-            tile_q=tile_q, tile_d=tile_d, interpret=interpret)
+            tile_q=tile_q, tile_d=tile_d)
     S, Q, _ = slots.shape
     drain_sw = jnp.asarray(drain_sw, jnp.int32)
     drain_slot = jnp.asarray(drain_slot, jnp.int32)
@@ -124,16 +126,15 @@ def olaf_forward(slots, counts, updates, clusters, gate, reset_slots,
     return new_slots, new_counts, drained, hops
 
 
-@functools.partial(jax.jit, static_argnames=("tile_q", "tile_d", "interpret"))
+@functools.partial(jax.jit, static_argnames=("tile_q", "tile_d"))
 def olaf_enqueue(state: JaxQueueState, clusters, workers, gen_times, rewards,
                  payloads, reward_threshold=jnp.inf, capacity=None,
-                 screen=None, *, tile_q: int = 8, tile_d: int = 512,
-                 interpret: bool = _INTERPRET) -> JaxQueueState:
+                 screen=None, *, tile_q: int = 8, tile_d: int = 512) -> JaxQueueState:
     """Fused single-launch burst enqueue (Algorithm 1 for U updates).
 
     Drop-in replacement for ``repro.core.olaf_queue.jax_enqueue_burst`` (the
     oracle it is tested against): the ``_burst_resolve`` scalar scan runs
-    inside the kernel from SMEM scalar-prefetch operands and the payload
+    inside the kernel from SMEM per-update scalars and the payload
     telescoped-mean runs on the MXU over the same (Q-tile × D-tile) grid as
     ``olaf_combine`` — one kernel launch for the whole burst instead of a
     scan + einsum + blend pipeline. ``screen`` optionally withholds rows
@@ -145,7 +146,7 @@ def olaf_enqueue(state: JaxQueueState, clusters, workers, gen_times, rewards,
         state.n_agg, state.n_repl, state.payload,
         clusters, workers, gen_times, rewards, payloads, reward_threshold,
         capacity, state.n_screened, screen, tile_q=tile_q, tile_d=tile_d,
-        interpret=interpret)
+        interpret=_interpret())
     return JaxQueueState(
         cluster=mi[0], worker=mi[1], seq=mi[2], gen_time=mf[0], reward=mf[1],
         agg_count=mi[3], replaceable=mi[4].astype(bool), payload=new_payload,
@@ -178,12 +179,11 @@ def _olaf_step_unpack(new_payload, drained, mi, mf, di, df):
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "k", "tile_q", "tile_d", "interpret", "impl"), donate_argnums=0)
+    "k", "tile_q", "tile_d", "impl"), donate_argnums=0)
 def olaf_step(state: JaxQueueState, clusters, workers, gen_times, rewards,
               payloads, reward_threshold=jnp.inf, send=None, capacity=None,
               active_workers=None, screen=None, *, k: int, tile_q: int = 8,
-              tile_d: int = 512, interpret: bool = _INTERPRET,
-              impl: str = "auto"):
+              tile_d: int = 512, impl: str = "auto"):
     """Fused full-cycle data-plane step: burst enqueue → drain-k, one launch.
 
     Drop-in replacement for the composed ``jax_enqueue_burst →
@@ -198,8 +198,8 @@ def olaf_step(state: JaxQueueState, clusters, workers, gen_times, rewards,
     kernel (the TPU fast path — resolve, drain select and payload movement
     share one grid); ``"xla"`` is the same cycle as one fused XLA
     executable (the fast path where the interpreter would run the kernel
-    body, i.e. this CPU container); ``"auto"`` picks ``"pallas"`` when
-    compiled (REPRO_PALLAS_COMPILED=1) and ``"xla"`` under interpretation.
+    body, i.e. off a TPU); ``"auto"`` picks ``"pallas"`` on a TPU backend
+    and ``"xla"`` on any other. An empty burst always takes ``"xla"``.
 
     ``active_workers`` (bool (W,)) treats drained rows of crashed workers
     as expired — slot freed, row masked invalid so it is never applied
@@ -214,7 +214,8 @@ def olaf_step(state: JaxQueueState, clusters, workers, gen_times, rewards,
     if impl == "auto":
         # an empty burst (drain-only final flush) has no (U, Dt) tile to
         # grid over — always take the XLA path for it
-        impl = "xla" if (interpret or clusters.shape[0] == 0) else "pallas"
+        impl = "xla" if (_interpret() or clusters.shape[0] == 0) \
+            else "pallas"
     if impl == "xla":
         return jax_olaf_step(state, clusters, workers, gen_times, rewards,
                              payloads, k, reward_threshold, send, capacity,
@@ -225,7 +226,7 @@ def olaf_step(state: JaxQueueState, clusters, workers, gen_times, rewards,
         state.n_agg, state.n_repl, state.payload,
         clusters, workers, gen_times, rewards, payloads, k, reward_threshold,
         send, capacity, state.n_screened, screen, tile_q=tile_q,
-        tile_d=tile_d, interpret=interpret)
+        tile_d=tile_d, interpret=_interpret())
     state, out = _olaf_step_unpack(*outs)
     if active_workers is not None:
         out = expire_inactive_drains(out, active_workers)
@@ -233,12 +234,11 @@ def olaf_step(state: JaxQueueState, clusters, workers, gen_times, rewards,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "k", "tile_q", "tile_d", "interpret", "impl"), donate_argnums=0)
+    "k", "tile_q", "tile_d", "impl"), donate_argnums=0)
 def olaf_step_multi(states: JaxQueueState, clusters, workers, gen_times,
                     rewards, payloads, reward_threshold=jnp.inf, send=None,
                     capacity=None, screen=None, *, k: int, tile_q: int = 8,
-                    tile_d: int = 512, interpret: bool = _INTERPRET,
-                    impl: str = "auto"):
+                    tile_d: int = 512, impl: str = "auto"):
     """Multi-queue fused cycle: every operand carries a leading S axis.
 
     ``states`` is a JaxQueueState of (S, Q)/(S, Q, D)/(S,) arrays; burst
@@ -249,7 +249,8 @@ def olaf_step_multi(states: JaxQueueState, clusters, workers, gen_times,
     variant that splits S over a device mesh.
     """
     if impl == "auto":
-        impl = "xla" if (interpret or clusters.shape[1] == 0) else "pallas"
+        impl = "xla" if (_interpret() or clusters.shape[1] == 0) \
+            else "pallas"
     if impl == "xla":
         if send is None:
             send = jnp.ones(clusters.shape, bool)
@@ -271,7 +272,7 @@ def olaf_step_multi(states: JaxQueueState, clusters, workers, gen_times,
         states.n_dropped, states.n_agg, states.n_repl, states.payload,
         clusters, workers, gen_times, rewards, payloads, k, reward_threshold,
         send, capacity, states.n_screened, screen, tile_q=tile_q,
-        tile_d=tile_d, interpret=interpret)
+        tile_d=tile_d, interpret=_interpret())
     return _olaf_step_unpack(*outs)
 
 
@@ -311,10 +312,9 @@ def olaf_burst_multi(states: JaxQueueState, clusters, workers, gen_times,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "causal", "window", "q_offset", "block_q", "block_k", "interpret"))
+    "causal", "window", "q_offset", "block_q", "block_k"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
-                    q_offset: int = 0, block_q: int = 512, block_k: int = 512,
-                    interpret: bool = _INTERPRET):
+                    q_offset: int = 0, block_q: int = 512, block_k: int = 512):
     """Flash attention in the model's (B, S, H, Dh) layout (kv pre-expanded)."""
     B, Sq, H, Dh = q.shape
     qf = jnp.moveaxis(q, 2, 1).reshape(B * H, Sq, Dh)
@@ -322,13 +322,12 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     vf = jnp.moveaxis(v, 2, 1).reshape(B * H, v.shape[1], Dh)
     out = flash_attention_pallas(qf, kf, vf, causal=causal, window=window,
                                  q_offset=q_offset, block_q=block_q,
-                                 block_k=block_k, interpret=interpret)
+                                 block_k=block_k, interpret=_interpret())
     return jnp.moveaxis(out.reshape(B, H, Sq, Dh), 1, 2)
 
 
-@functools.partial(jax.jit, static_argnames=("block_s", "interpret"))
-def decode_attention(q, k_cache, v_cache, pos, *, block_s: int = 512,
-                     interpret: bool = _INTERPRET):
+@functools.partial(jax.jit, static_argnames=("block_s",))
+def decode_attention(q, k_cache, v_cache, pos, *, block_s: int = 512):
     """GQA decode attention. q: (B,KV,rep,Dh); caches (B,S,KV,Dh); pos (B,)."""
     return decode_attention_pallas(q, k_cache, v_cache, pos, block_s=block_s,
-                                   interpret=interpret)
+                                   interpret=_interpret())

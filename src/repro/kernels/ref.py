@@ -18,7 +18,8 @@ def olaf_combine_ref(slots, counts, updates, clusters, gate):
     Q = slots.shape[0]
     onehot = (jax.nn.one_hot(clusters, Q, dtype=updates.dtype)
               * gate.astype(updates.dtype)[:, None])  # (U,Q)
-    sums = jnp.einsum("uq,ud->qd", onehot, updates.astype(jnp.float32))
+    sums = jnp.einsum("uq,ud->qd", onehot, updates.astype(jnp.float32),
+                      precision=jax.lax.Precision.HIGHEST)
     hits = onehot.sum(axis=0)  # (Q,)
     acc = slots.astype(jnp.float32) * counts.astype(jnp.float32)[:, None] + sums
     denom = jnp.maximum(counts.astype(jnp.float32) + hits, 1.0)

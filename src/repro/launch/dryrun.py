@@ -194,8 +194,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         compiled = lowered.compile()
         t_compile = time.time() - t0
         mem = compiled.memory_analysis()
-        from repro.launch.mesh import cost_analysis_dict
-        cost = cost_analysis_dict(compiled)
+        cost = compiled.cost_analysis()
         if verbose:  # assignment-literal dump: proves it fits + flops/bytes
             print(mem)
             print({k: cost.get(k) for k in

@@ -11,12 +11,8 @@ import jax
 
 
 def _make_mesh(shape, axes) -> jax.sharding.Mesh:
-    # jax >= 0.5 takes axis_types (Auto is the default behaviour anyway);
-    # older jax (this container: 0.4.x) has no such parameter.
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(shape, axes,
-                             axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
@@ -28,15 +24,6 @@ def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
 def make_host_mesh(data: int = 1, model: int = 1) -> jax.sharding.Mesh:
     """Small mesh over however many (host) devices exist — tests/examples."""
     return _make_mesh((data, model), ("data", "model"))
-
-
-def cost_analysis_dict(compiled) -> dict:
-    """``Compiled.cost_analysis()`` returns one dict in jax >= 0.5 but a
-    per-device list of dicts in 0.4.x — normalize to the dict."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
 
 
 # TPU v5e hardware model for the roofline terms (assignment constants)

@@ -40,7 +40,7 @@ import numpy as np
 from repro.configs import SHAPES, get_config
 from repro.configs.base import ArchConfig, ShapeCfg
 from repro.launch.hlo_analysis import analyze_collectives
-from repro.launch.mesh import HW, cost_analysis_dict, make_production_mesh
+from repro.launch.mesh import HW, make_production_mesh
 from repro.models import api
 from repro.models.module import count_params
 from repro.models.transformer import period_len, split_plan
@@ -72,7 +72,7 @@ def _compile_costs(cfg: ArchConfig, shape: ShapeCfg, mesh) -> Dict[str, float]:
     from repro.launch.dryrun import build_lowering
     lowered = build_lowering(cfg, shape, mesh)
     compiled = lowered.compile()
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis()
     coll = analyze_collectives(compiled.as_text())
     return dict(flops=float(ca.get("flops", 0.0)),
                 bytes=float(ca.get("bytes accessed", 0.0)),

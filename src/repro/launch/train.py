@@ -20,6 +20,7 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import time
+from typing import Dict, List
 
 import jax
 import jax.numpy as jnp
@@ -28,6 +29,7 @@ import numpy as np
 from repro.checkpoint.ckpt import latest_step, restore_checkpoint, save_checkpoint
 from repro.configs import SHAPES, get_config
 from repro.data.pipeline import DataConfig, SyntheticLM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import api
 from repro.optim.optimizers import OptConfig, apply_updates, init_opt_state
 
@@ -71,7 +73,19 @@ def run_sync(cfg, args) -> float:
     return losses[-1]
 
 
-def run_olaf_async(cfg, args) -> float:
+@dataclasses.dataclass
+class OlafAsyncResult:
+    """What a :func:`run_olaf_async` run leaves: per applied PS step the
+    mean worker loss of its burst, the drained rows it applied and the raw
+    updates they combine; and host copies of the final queue's metadata
+    and counters (the payload stays on the device)."""
+    losses: List[float]
+    applied: List[int]
+    combined: List[int]
+    queue: Dict[str, np.ndarray]
+
+
+def run_olaf_async(cfg, args) -> OlafAsyncResult:
     """OLAF-async data parallelism: N worker groups compute gradients on
     their own shard streams and push flattened updates through the device-
     resident OlafQueue; the PS side drains the queue and applies combined
@@ -206,7 +220,8 @@ def run_olaf_async(cfg, args) -> float:
         else:
             n_stale = jnp.int32(0)
         wts = out["valid"] * out["agg_count"].astype(jnp.float32)
-        g_mean = jnp.einsum("k,kd->d", wts, out["payload"]) \
+        g_mean = jnp.einsum("k,kd->d", wts, out["payload"],
+                            precision=jax.lax.Precision.HIGHEST) \
             / jnp.maximum(wts.sum(), 1.0)
         if screen_on:
             # robust fallback: when the screen flags more than
@@ -287,7 +302,8 @@ def run_olaf_async(cfg, args) -> float:
         print(f"resumed olaf-async from step {start_it}")
 
     pending = []  # device-side per-step stats, drained in batches
-    log_rows = []  # host-side (step, loss, combined) after each flush
+    # host-side (step, loss, combined, applied) after each flush
+    log_rows = []
     deferred_total = [0]  # txctl-gated (deferred) burst rows
     stale_total = [0]  # PS-rejected rows past the staleness bound
     screened_total = [0]  # ingress-screened (integrity-rejected) burst rows
@@ -298,7 +314,8 @@ def run_olaf_async(cfg, args) -> float:
         # one host sync for the whole batch of buffered scalars
         for row in jax.device_get(pending):
             step = len(log_rows) + 1
-            log_rows.append((step, float(row["loss"]), int(row["combined"])))
+            log_rows.append((step, float(row["loss"]), int(row["combined"]),
+                             int(row["applied"])))
             deferred_total[0] += int(row["deferred"])
             stale_total[0] += int(row["stale"])
             screened_total[0] += int(row["screened"])
@@ -356,7 +373,7 @@ def run_olaf_async(cfg, args) -> float:
         if len(pending) >= flush_every:
             flush()
             if args.log_every:
-                step, loss_v, combined = log_rows[-1]
+                step, loss_v, combined, _ = log_rows[-1]
                 print(f"applied {step}: loss {loss_v:.4f} "
                       f"(combined {combined} updates)")
         if args.ckpt and args.ckpt_every and (it + 1) % args.ckpt_every == 0:
@@ -367,7 +384,7 @@ def run_olaf_async(cfg, args) -> float:
         save_checkpoint(args.ckpt, args.steps, params, opt_state,
                         aux=snapshot_aux())
     wall = time.time() - t0
-    losses = [l for _, l, _ in log_rows]
+    losses = [l for _, l, _, _ in log_rows]
     horizon = float(worker_next[np.isfinite(worker_next)].max())
     avg_aom = float(jax_aom_average(aom, horizon))
     if losses:
@@ -378,7 +395,13 @@ def run_olaf_async(cfg, args) -> float:
               f"screened {screened_total[0]}; "
               f"avg AoM {avg_aom:.3f} (virtual); "
               f"{args.steps / max(wall, 1e-9):.2f} steps/s")
-    return losses[-1] if losses else float("nan")
+    meta = {f: np.asarray(getattr(queue, f)) for f in (
+        "cluster", "worker", "seq", "agg_count", "replaceable", "next_seq",
+        "n_dropped", "n_agg", "n_repl", "n_screened")}
+    return OlafAsyncResult(losses=losses,
+                           applied=[a for _, _, _, a in log_rows],
+                           combined=[c for _, _, c, _ in log_rows],
+                           queue=meta)
 
 
 def run_scenario(args):
@@ -407,6 +430,12 @@ def run_scenario(args):
     sim_mesh = None
     if args.sim_shards > 1 or args.sim_worker_shards > 1:
         from repro.distributed.sharding import vecsim_mesh
+        need = args.sim_shards * args.sim_worker_shards
+        if need > len(jax.devices()):
+            raise SystemExit(
+                f"--sim-shards {args.sim_shards} x --sim-worker-shards "
+                f"{args.sim_worker_shards} needs {need} devices, only "
+                f"{len(jax.devices())} present")
         n_sw = len(sim_cfg.switches) if sim_cfg is not None else 3
         sim_mesh = vecsim_mesh(min(n_sw, args.sim_shards),
                                worker_shards=args.sim_worker_shards)
@@ -518,6 +547,7 @@ def main():
                          "of the plain weighted mean")
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
+    enable_compile_cache()
     if args.mode == "scenario":
         run_scenario(args)
         return

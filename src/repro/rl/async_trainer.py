@@ -296,7 +296,7 @@ class AsyncDRLTrainer:
 def run_hybrid_ppo(*, env: str = "cartpole", ppo_cfg: Optional[PPOConfig] = None,
                    ps_cfg: Optional[PSConfig] = None, n_envs: int = 2,
                    local_lr: float = 5e-3, seed: int = 0,
-                   interpret: bool = True, sharded: bool = True,
+                   sharded: bool = True,
                    batched: bool = True, topology=None,
                    flush_cadence: bool = True,
                    sim_impl: Optional[str] = None, **multihop_kw):
@@ -356,7 +356,7 @@ def run_hybrid_ppo(*, env: str = "cartpole", ppo_cfg: Optional[PPOConfig] = None
         flat, _ = flatten_params(grads)
         return np.asarray(flat, np.float32), float(mean_reward)
 
-    hyb, cfg = run_hybrid_multihop(dim, seed=seed, interpret=interpret,
+    hyb, cfg = run_hybrid_multihop(dim, seed=seed,
                                    payload_source=payload_source,
                                    sim_cfg=cfg, sharded=sharded,
                                    batched=batched,

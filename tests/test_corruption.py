@@ -247,8 +247,7 @@ def test_screen_gate_threads_through_queue_ops():
         c, w, t, r, p = burst()
         scr = jnp.asarray(rng.random(U) < 0.4)
         st_o = jax_enqueue_burst(st_o, c, w, t, r, p, 0.5, screen=scr)
-        st_p = ops.olaf_enqueue(st_p, c, w, t, r, p, 0.5, None, scr,
-                                interpret=True)
+        st_p = ops.olaf_enqueue(st_p, c, w, t, r, p, 0.5, None, scr)
     for f in ("cluster", "worker", "seq", "agg_count", "next_seq",
               "n_dropped", "n_agg", "n_repl", "n_screened"):
         np.testing.assert_array_equal(np.asarray(getattr(st_o, f)),
@@ -265,8 +264,7 @@ def test_screen_gate_threads_through_queue_ops():
         st_x, out_x = ops.olaf_step(st_x, c, w, t, r, p, 0.5, snd, None,
                                     None, scr, k=k, impl="xla")
         st_p, out_p = ops.olaf_step(st_p, c, w, t, r, p, 0.5, snd, None,
-                                    None, scr, k=k, impl="pallas",
-                                    interpret=True)
+                                    None, scr, k=k, impl="pallas")
         for key in out_x:
             np.testing.assert_allclose(np.asarray(out_x[key]),
                                        np.asarray(out_p[key]), atol=1e-5,

@@ -10,7 +10,6 @@ float-association tolerance, across 100+ randomized bursts covering the
 full-queue, same-worker-replace and reward-gated paths, and across grid
 tilings (multi-tile grids exercise the SMEM scratch reuse between steps).
 """
-import os
 import zlib
 
 import numpy as np
@@ -19,18 +18,12 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-if (os.environ.get("REPRO_PALLAS_COMPILED") == "1"
-        and jax.default_backend() != "tpu"):
-    pytest.skip("compiled Pallas kernels need a TPU backend",
-                allow_module_level=True)
-
 from repro.core.olaf_queue import (jax_dequeue_burst, jax_enqueue_burst,
                                    jax_queue_init)
 from repro.kernels import ops
 
 # the randomized oracle sweeps are long; the CI fast lane skips them
-# (-m "not slow") — the dedicated pallas-kernels matrix job and the
-# full-suite job still run this module
+# (-m "not slow") — the full-suite job still runs this module
 pytestmark = pytest.mark.slow
 
 # name, Q, U, n_clusters, n_workers, reward_threshold, n_bursts

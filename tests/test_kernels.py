@@ -1,19 +1,11 @@
-"""Per-kernel allclose sweeps: Pallas (interpret=True) vs the pure-jnp
+"""Per-kernel allclose sweeps: Pallas (interpreted off a TPU) vs the pure-jnp
 oracles in ``repro.kernels.ref``, over shapes and dtypes."""
-import os
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
-
-if (os.environ.get("REPRO_PALLAS_COMPILED") == "1"
-        and jax.default_backend() != "tpu"):
-    pytest.skip("compiled Pallas kernels need a TPU backend",
-                allow_module_level=True)
-
 
 def rand(rng, shape, dtype):
     x = rng.normal(size=shape).astype(np.float32)
@@ -23,7 +15,7 @@ def rand(rng, shape, dtype):
 class TestOlafCombine:
     @pytest.mark.parametrize("Q,U,D", [(4, 3, 128), (8, 16, 512), (2, 1, 1024),
                                        (16, 32, 256), (8, 256, 256),
-                                       (32, 257, 128)])
+                                       (32, 257, 128), (3, 5, 200)])
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
     def test_matches_ref(self, Q, U, D, dtype):
         rng = np.random.default_rng(Q * 101 + U)
@@ -197,8 +189,7 @@ class TestFlashAttention:
         k = rand(rng, (B, S, H, Dh), jnp.float32)
         v = rand(rng, (B, S, H, Dh), jnp.float32)
         from repro.models.layers import full_attention
-        got = ops.flash_attention(q, k, v, causal=True, block_q=64, block_k=64,
-                                  interpret=True)
+        got = ops.flash_attention(q, k, v, causal=True, block_q=64, block_k=64)
         want = full_attention(q, k, v, causal=True)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-5, atol=2e-5)
@@ -215,7 +206,7 @@ class TestDecodeAttention:
         kc = rand(rng, (B, S, KV, Dh), dtype)
         vc = rand(rng, (B, S, KV, Dh), dtype)
         pos = jnp.asarray(rng.integers(1, S, (B,)), jnp.int32)
-        got = ops.decode_attention(q, kc, vc, pos, block_s=blk, interpret=True)
+        got = ops.decode_attention(q, kc, vc, pos, block_s=blk)
         want = ref.decode_attention_ref(q, kc, vc, pos)
         tol = 2e-5 if dtype == jnp.float32 else 2e-2
         np.testing.assert_allclose(np.asarray(got, np.float32),
@@ -230,9 +221,9 @@ class TestDecodeAttention:
         kc = rand(rng, (B, S, KV, Dh), jnp.float32)
         vc = rand(rng, (B, S, KV, Dh), jnp.float32)
         pos = jnp.array([5, 60], jnp.int32)
-        got = ops.decode_attention(q, kc, vc, pos, block_s=64, interpret=True)
+        got = ops.decode_attention(q, kc, vc, pos, block_s=64)
         # poison the masked region; result must not change
         kc2 = kc.at[:, 100:].set(1e4)
         vc2 = vc.at[:, 100:].set(-1e4)
-        got2 = ops.decode_attention(q, kc2, vc2, pos, block_s=64, interpret=True)
+        got2 = ops.decode_attention(q, kc2, vc2, pos, block_s=64)
         np.testing.assert_allclose(np.asarray(got), np.asarray(got2))

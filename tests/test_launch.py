@@ -82,8 +82,7 @@ class TestLoweringOnHostMesh:
         cfg = get_config(arch).reduced()
         lowered = build_lowering(cfg, TINY, mesh)
         compiled = lowered.compile()
-        from repro.launch.mesh import cost_analysis_dict
-        assert cost_analysis_dict(compiled).get("flops", 0) > 0
+        assert compiled.cost_analysis().get("flops", 0) > 0
 
     def test_decode_step_lowers_and_compiles(self):
         from repro.launch.dryrun import build_lowering
@@ -91,6 +90,41 @@ class TestLoweringOnHostMesh:
         cfg = get_config("smollm-360m").reduced()
         compiled = build_lowering(cfg, TINY_DECODE, mesh).compile()
         assert compiled.memory_analysis().temp_size_in_bytes >= 0
+
+
+class TestEntryPoints:
+    @pytest.mark.parametrize("env_dir", [None, "/elsewhere/jax-cache"])
+    def test_compile_cache_dir(self, env_dir, monkeypatch):
+        """``JAX_COMPILATION_CACHE_DIR`` wins and nothing else is set;
+        without it the cache goes to one fixed, git-ignored checkout path."""
+        from repro.launch import compile_cache
+        updates = []
+        monkeypatch.setattr(compile_cache.jax.config, "update",
+                            lambda name, value: updates.append((name, value)))
+        if env_dir is None:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        else:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+        got = compile_cache.enable_compile_cache()
+        if env_dir is not None:
+            assert got == env_dir and updates == []
+            return
+        root = Path(compile_cache.__file__).resolve().parents[3]
+        assert got == str(root / ".jax_cache")
+        assert updates == [("jax_compilation_cache_dir", got)]
+        assert ".jax_cache/" in (root / ".gitignore").read_text().split()
+
+    def test_scenario_rejects_more_shards_than_devices(self):
+        """The sharded scenario CLI refuses a mesh larger than the devices
+        present instead of shrinking it."""
+        import argparse
+        from repro.launch.train import run_scenario
+        n = len(jax.devices())
+        args = argparse.Namespace(topology="multihop", seed=0, sim_dt=None,
+                                  sim_shards=n + 1, sim_worker_shards=1,
+                                  sim_dim=8, sim_impl="vectorized")
+        with pytest.raises(SystemExit, match=f"needs {n + 1} devices"):
+            run_scenario(args)
 
 
 class TestHloAnalysis:

@@ -10,8 +10,6 @@ express — chains, fat-tree, multi-PS egress and fully randomized DAGs —
 with the batched ``feed_window`` consumer proven event-for-event equivalent
 to the per-event reference on every sampled spec.
 """
-import os
-
 import numpy as np
 import pytest
 
@@ -29,10 +27,6 @@ from repro.core.topology import (SwitchSpec, TopologySpec, build_sim_cfg,
 from tests.test_hybrid_window import _assert_results_equal, _payload_source
 
 DIM = 24
-
-_COMPILED_OFF_TPU = (os.environ.get("REPRO_PALLAS_COMPILED") == "1"
-                     and jax.default_backend() != "tpu")
-
 
 # ---------------------------------------------------------------------------
 # Spec compilation
@@ -135,8 +129,6 @@ def test_capacity_caps_logical_slots():
                                    np.asarray(small.payload), rtol=1e-6)
 
 
-@pytest.mark.skipif(_COMPILED_OFF_TPU,
-                    reason="compiled Pallas kernels need a TPU backend")
 def test_olaf_step_kernel_capacity_matches_oracle():
     """The fused Pallas cycle honors the logical capacity exactly like the
     XLA oracle (drop-when-logically-full, append below the cap only)."""
@@ -145,8 +137,7 @@ def test_olaf_step_kernel_capacity_matches_oracle():
     for cap in (3, 5, 8):
         b = _burst(rng, 10, 32)
         st_p, out_p = ops.olaf_step(jax_queue_init(8, 32), *b, k=3,
-                                    capacity=cap, impl="pallas",
-                                    interpret=True)
+                                    capacity=cap, impl="pallas")
         st_x, out_x = ops.olaf_step(jax_queue_init(8, 32), *b, k=3,
                                     capacity=cap, impl="xla")
         for f in ("cluster", "seq", "agg_count", "n_dropped"):
@@ -159,8 +150,6 @@ def test_olaf_step_kernel_capacity_matches_oracle():
                                    rtol=1e-5, atol=1e-6)
 
 
-@pytest.mark.skipif(_COMPILED_OFF_TPU,
-                    reason="compiled Pallas kernels need a TPU backend")
 def test_olaf_step_multi_heterogeneous_capacities():
     """One padded (S, Qmax) multi-queue launch with a per-switch capacity
     vector equals per-switch single-queue cycles at their exact sizes."""

@@ -323,24 +323,43 @@ def test_ring_insert_vec_matches_sequential(trial):
     occupied = rng.random(R) < 0.5
     t[occupied] = rng.random(occupied.sum()).astype(np.float32)
     ring_a = {"time": jnp.asarray(t),
-              "val": jnp.asarray(rng.integers(0, 99, R), jnp.int32)}
+              "val": jnp.asarray(rng.integers(0, 99, R), jnp.int32),
+              "pay": jnp.asarray(rng.normal(size=(R, 5)), jnp.float32)}
     ring_b = {k: v for k, v in ring_a.items()}
     mask = jnp.asarray(rng.random(N) < 0.6)
     rows = {"time": jnp.asarray(rng.random(N), jnp.float32),
-            "val": jnp.asarray(rng.integers(100, 199, N), jnp.int32)}
+            "val": jnp.asarray(rng.integers(100, 199, N), jnp.int32),
+            "pay": jnp.asarray(rng.normal(size=(N, 5)), jnp.float32)}
     ovf0 = jnp.asarray(False)
     ra, oa = vecsim._ring_insert(ring_a, ovf0, mask, rows)
     rb, ob, slot = vecsim._ring_insert_vec(ring_b, ovf0, mask, rows)
-    np.testing.assert_array_equal(np.asarray(ra["time"]),
-                                  np.asarray(rb["time"]))
-    np.testing.assert_array_equal(np.asarray(ra["val"]),
-                                  np.asarray(rb["val"]))
+    for k in ("time", "val", "pay"):  # payload rows too, not just scalars
+        np.testing.assert_array_equal(np.asarray(ra[k]), np.asarray(rb[k]))
     assert bool(oa) == bool(ob)
     # returned landing slots point at the inserted rows
     for i in np.nonzero(np.asarray(mask))[0]:
         s = int(np.asarray(slot)[i])
         if s < R:
             assert int(np.asarray(rb["val"])[s]) >= 100
+
+
+@pytest.mark.parametrize("size,n,k", [
+    (16, 0, 3), (16, 5, 8), (16, 12, 8), (16, 15, 8), (16, 20, 8),
+    (16, 9, 0), (4, 1, 6), (4, 3, 8)])
+def test_append_matches_drop_scatter(size, n, k):
+    """``_append`` writes ``rows[:k]`` at ``buf[n:n + k]`` exactly as the
+    drop-mode scatter it replaces — rows past the end of the log are
+    dropped, including when the source batch is longer than the log."""
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(size * 100 + n * 10 + k)
+    N = 8
+    buf = jnp.asarray(rng.normal(size=(size, 3)), jnp.float32)
+    rows = jnp.asarray(rng.normal(size=(N, 3)), jnp.float32)
+    idx = jnp.where(jnp.arange(N) < k, n + jnp.arange(N), size + 1)
+    want = buf.at[idx].set(rows, mode="drop")
+    got = vecsim._append(buf, jnp.int32(n), rows, jnp.int32(k))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_scan_carry_is_donated():
