@@ -6,13 +6,15 @@
                      slot counts; optional multi-queue axis)
   olaf_enqueue     — fused burst enqueue: Algorithm 1 gating as an
                      in-kernel resolve over SMEM per-update scalars
-                     plus the telescoped-mean payload matmul, one launch
-                     per burst (oracle: olaf_queue.jax_enqueue_burst)
+                     plus the telescoped-mean payload pass on the VPU,
+                     one launch per burst (oracle:
+                     olaf_queue.jax_enqueue_burst)
   olaf_step        — the fused full-cycle data plane: burst resolve (with
                      a per-update transmission-control send gate), drain-k
                      oldest-valid selection, payload combine + drained-row
-                     gather on one (S × D-tile × Q-tile) grid — one launch
-                     per PS step; leading S axis batches switches (oracle:
+                     gather on one (S × D-tile × Q-tile) grid, its D-tile
+                     sized from the shapes — one launch per PS step;
+                     leading S axis batches switches (oracle:
                      olaf_queue.jax_olaf_step)
   flash_attention  — online-softmax attention, (BH, q_blocks, kv_blocks)
                      grid with VMEM scratch accumulators

@@ -290,16 +290,20 @@ def _burst_payload_tile(i, tile_q: int, slots_v, contrib, last_reset,
 
     ``slots_v``/``contrib`` (1, U) are the resolved per-update slot and
     survival flag, ``last_reset``/``counts`` (Qt, 1) the tile's last reset
-    index and pre-burst agg_count. One one-hot (Qt, U) × (U, Dt)
-    segment-sum on the MXU (exact: 0/1 weights at ``HIGHEST``) plus one
-    blend — ``jax_enqueue_burst``'s payload arithmetic.
+    index and pre-burst agg_count. The segment-sum runs on the VPU: a
+    static loop over the U burst rows, each selected into the slot rows it
+    contributes to and added in update order, then one blend —
+    ``jax_enqueue_burst``'s payload arithmetic.
     """
     U = updates.shape[0]
     qids = i * tile_q + jax.lax.broadcasted_iota(jnp.int32, (tile_q, U), 0)
     seg = jnp.where((slots_v == qids) & (contrib != 0), 1.0,
                     0.0).astype(jnp.float32)  # (Qt, U)
-    sums = jnp.dot(seg, updates.astype(jnp.float32),
-                   preferred_element_type=jnp.float32, precision=_HIGHEST)
+    updates = updates.astype(jnp.float32)
+    sums = jnp.zeros(old.shape, jnp.float32)
+    for u in range(U):
+        sums = sums + jnp.where(seg[:, u:u + 1] > 0, updates[u:u + 1, :],
+                                0.0)
     n_contrib = seg.sum(axis=1, keepdims=True)
     base_n = jnp.where(last_reset < 0, counts, 0).astype(jnp.float32)
     touched = (last_reset >= 0) | (n_contrib > 0)
@@ -365,7 +369,7 @@ def _enqueue_kernel(qc_ref, ui_ref, uf_ref, qi_ref, qf_ref, cnt_ref,
         meta_f_ref[0:1, :] = gt
         meta_f_ref[1:2, :] = rw
 
-    # ---- payload pass (every grid step, MXU) ----------------------------
+    # ---- payload pass (every grid step, VPU) ----------------------------
     out_ref[...] = _burst_payload_tile(
         i, tile_q, slots_scr[...], contrib_scr[...],
         _tile_rows(lastreset_scr, i, tile_q), cnt_ref[...], updates_ref[...],

@@ -15,12 +15,13 @@ top-k pass:
      kernel matches the ``jax_enqueue_burst → jax_dequeue_burst`` oracle
      row for row. A k-step selection loop over the (1, Q) rows, also at the
      first grid step.
-  3. **payload combine + gather** — on every (Q-tile × D-tile) grid step:
-     the telescoped-mean burst combine (one one-hot (Qt, U) × (U, Dt)
-     segment-sum on the MXU plus a blend), then the drained rows gathered
-     from the *combined* tiles by a one-hot (K, Qt) × (Qt, Dt) matmul
-     accumulated across Q-tiles, and the popped slots zeroed in the new
-     payload output.
+  3. **payload combine + gather** — on every (Q-tile × D-tile) grid step,
+     all on the VPU: the telescoped-mean burst combine (a static loop over
+     the U burst rows, each selected into the slot rows it contributes to,
+     plus a blend), then the drained rows selected from the *combined*
+     tile (a static loop over its Qt rows; each drained row takes exactly
+     one slot, so the select is exact), accumulated across Q-tiles, and the
+     popped slots zeroed in the new payload output.
 
 VMEM scratch carries the resolved slot/contribute assignment and the drain
 slot selection across grid steps (TPU grid steps run sequentially on one
@@ -28,7 +29,10 @@ core, so scratch written at a switch's first step is visible to all its
 later steps). The grid iterates (S, D-tiles, Q-tiles) with Q-tiles
 innermost: for a fixed D-tile every Q-tile is visited consecutively, so the
 (K, Dt) drained output block stays resident in VMEM while its cross-Q-tile
-accumulation runs. Only the burst's per-update scalars and the queue
+accumulation runs. Unless the caller fixes it, the D-tile is the widest
+that fits a VMEM budget (:func:`derive_tile_d`): a payload pass moves
+little data per grid step at narrow tiles, and the fixed cost of each step
+would then dominate. Only the burst's per-update scalars and the queue
 counters ride in SMEM (scalar prefetch); the per-slot metadata rows live in
 VMEM, where the resolve's vector ops run.
 
@@ -45,12 +49,44 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.olaf_combine import (_HIGHEST, _burst_payload_tile,
-                                        _pick_tile_d, _pick_tile_q,
-                                        _tile_rows, alg1_resolve)
+from repro.kernels.olaf_combine import (_burst_payload_tile, _pick_tile_d,
+                                        _pick_tile_q, _tile_rows,
+                                        alg1_resolve)
 
 _SENTINEL = jnp.iinfo(jnp.int32).max
 _NEG_INF = float("-inf")
+# VMEM the derived D-tile may fill: half the 16 MiB that Mosaic grants a
+# kernel by default on a TPU v5e. At the LM gradient's width, tiles of 12K
+# to 20K lanes ran fastest there; 30K lanes took 10% longer (PERF.md)
+VMEM_BUDGET = 8 * 1024 * 1024
+# f32 (Qt, Dt) temporaries of the payload pass: the segment-sum, the blend,
+# the combined tile and the cleared output tile
+_TEMP_TILES = 4
+
+
+def _sublanes(rows: int) -> int:
+    """Rows as VMEM holds them: rounded up to whole 8-sublane tiles."""
+    return -(-rows // 8) * 8
+
+
+def tile_d_bytes(tile_d: int, U: int, tile_q: int, k: int,
+                 itemsize: int = 4) -> int:
+    """VMEM bytes of one grid step's payload blocks at D-tile ``tile_d``:
+    the ``updates (U, Dt)``, ``slotpay (Qt, Dt)``, ``out (Qt, Dt)`` and
+    ``drained (K, Dt)`` blocks, each double-buffered, plus the f32
+    temporaries of the combine and the drained-row select."""
+    blocks = 2 * (_sublanes(U) + 2 * _sublanes(tile_q) + _sublanes(k))
+    temps = _TEMP_TILES * _sublanes(tile_q) + _sublanes(k)
+    return tile_d * (blocks * itemsize + temps * 4)
+
+
+def derive_tile_d(D: int, U: int, tile_q: int, k: int,
+                  itemsize: int = 4) -> int:
+    """The widest D-tile whose blocks fit :data:`VMEM_BUDGET`: a multiple
+    of 128 lanes, or the whole row when that fits."""
+    per_lane = tile_d_bytes(1, U, tile_q, k, itemsize)
+    lanes = max(128, VMEM_BUDGET // per_lane // 128 * 128)
+    return D if D <= lanes else lanes
 
 
 def _olaf_step_kernel(qc_ref, ui_ref, uf_ref, qi_ref, qf_ref, cnt_ref,
@@ -170,21 +206,18 @@ def _olaf_step_kernel(qc_ref, ui_ref, uf_ref, qi_ref, qf_ref, cnt_ref,
         meta_f_ref[0, 0:1, :] = gt
         meta_f_ref[0, 1:2, :] = jnp.where(pop, _NEG_INF, rw)
 
-    # ---- 3. payload pass (every grid step, MXU) --------------------------
+    # ---- 3. payload pass (every grid step, VPU) --------------------------
     combined = _burst_payload_tile(
         i, tile_q, slots_scr[...], contrib_scr[...],
         _tile_rows(lastreset_scr, i, tile_q), cnt_ref[0], updates_ref[0],
         slotpay_ref[0])  # post-enqueue, pre-drain tile
 
     # drained-row gather from the combined tile: each row selects exactly
-    # one slot, so the cross-tile accumulation is exact (single-term sums;
-    # HIGHEST keeps the one-hot matmul an f32 copy)
-    tile_qids = i * tile_q + jax.lax.broadcasted_iota(
-        jnp.int32, (k, tile_q), 1)
-    onehot_k = jnp.where(dslot_scr[...] == tile_qids, 1.0,
-                         0.0).astype(jnp.float32)  # (K, Qt)
-    part = jnp.dot(onehot_k, combined, preferred_element_type=jnp.float32,
-                   precision=_HIGHEST)  # (K, Dt)
+    # one slot, so the cross-tile accumulation is exact (single-term sums)
+    dslot = dslot_scr[...]  # (K, 1)
+    part = jnp.zeros((k, combined.shape[1]), jnp.float32)
+    for q in range(tile_q):
+        part = jnp.where(dslot == i * tile_q + q, combined[q:q + 1, :], part)
     popped_tile = _tile_rows(popped_scr, i, tile_q) != 0  # (Qt, 1)
 
     out_ref[0] = jnp.where(popped_tile, 0.0, combined).astype(out_ref.dtype)
@@ -203,7 +236,7 @@ def olaf_step_pallas(cluster, worker, seq, gen_time, reward, agg_count,
                      payload, clusters, workers, gen_times, rewards,
                      payloads, k: int, reward_threshold=float("inf"),
                      send=None, capacity=None, n_screened=0, screen=None,
-                     *, tile_q: int = 8, tile_d: int = 512,
+                     *, tile_q: int = 8, tile_d: int | None = None,
                      interpret: bool):
     """Single-launch fused enqueue→drain cycle over raw queue-state arrays.
 
@@ -213,9 +246,10 @@ def olaf_step_pallas(cluster, worker, seq, gen_time, reward, agg_count,
     Returns ``(new_payload, drained_payload (…, K, D), meta_i (…, 10, Q),
     meta_f (…, 2, Q), drain_i (…, 4, K), drain_f (…, 2, K))`` — see
     :func:`_olaf_step_kernel` for the packing. The JaxQueueState-typed
-    wrapper lives in ``repro.kernels.ops.olaf_step``. ``interpret=True``
-    runs the kernel body through the Pallas interpreter (any backend);
-    ``False`` compiles it with Mosaic for a TPU.
+    wrapper lives in ``repro.kernels.ops.olaf_step``. ``tile_d=None``
+    derives the D-tile from the shapes (:func:`derive_tile_d`).
+    ``interpret=True`` runs the kernel body through the Pallas interpreter
+    (any backend); ``False`` compiles it with Mosaic for a TPU.
     """
     squeeze = payload.ndim == 2
     if squeeze:
@@ -235,7 +269,8 @@ def olaf_step_pallas(cluster, worker, seq, gen_time, reward, agg_count,
     U = clusters.shape[1]
     k = min(int(k), Q)
     tile_q = _pick_tile_q(Q, tile_q)
-    tile_d = _pick_tile_d(D, tile_d)
+    tile_d = (derive_tile_d(D, U, tile_q, k, payload.dtype.itemsize)
+              if tile_d is None else _pick_tile_d(D, tile_d))
     i32, f32 = jnp.int32, jnp.float32
     if send is None:
         send = jnp.ones((S, U), i32)

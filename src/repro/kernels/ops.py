@@ -135,7 +135,7 @@ def olaf_enqueue(state: JaxQueueState, clusters, workers, gen_times, rewards,
     Drop-in replacement for ``repro.core.olaf_queue.jax_enqueue_burst`` (the
     oracle it is tested against): the ``_burst_resolve`` scalar scan runs
     inside the kernel from SMEM per-update scalars and the payload
-    telescoped-mean runs on the MXU over the same (Q-tile × D-tile) grid as
+    telescoped-mean runs on the VPU over the same (Q-tile × D-tile) grid as
     ``olaf_combine`` — one kernel launch for the whole burst instead of a
     scan + einsum + blend pipeline. ``screen`` optionally withholds rows
     flagged by the ingress integrity gate (``jax_screen_mask``).
@@ -183,7 +183,7 @@ def _olaf_step_unpack(new_payload, drained, mi, mf, di, df):
 def olaf_step(state: JaxQueueState, clusters, workers, gen_times, rewards,
               payloads, reward_threshold=jnp.inf, send=None, capacity=None,
               active_workers=None, screen=None, *, k: int, tile_q: int = 8,
-              tile_d: int = 512, impl: str = "auto"):
+              tile_d: int | None = None, impl: str = "auto"):
     """Fused full-cycle data-plane step: burst enqueue → drain-k, one launch.
 
     Drop-in replacement for the composed ``jax_enqueue_burst →
@@ -196,7 +196,8 @@ def olaf_step(state: JaxQueueState, clusters, workers, gen_times, rewards,
 
     ``impl`` selects the execution path: ``"pallas"`` is the single-launch
     kernel (the TPU fast path — resolve, drain select and payload movement
-    share one grid); ``"xla"`` is the same cycle as one fused XLA
+    share one grid, whose D-tile ``tile_d=None`` sizes from the shapes and
+    a VMEM budget); ``"xla"`` is the same cycle as one fused XLA
     executable (the fast path where the interpreter would run the kernel
     body, i.e. off a TPU); ``"auto"`` picks ``"pallas"`` on a TPU backend
     and ``"xla"`` on any other. An empty burst always takes ``"xla"``.
@@ -238,7 +239,7 @@ def olaf_step(state: JaxQueueState, clusters, workers, gen_times, rewards,
 def olaf_step_multi(states: JaxQueueState, clusters, workers, gen_times,
                     rewards, payloads, reward_threshold=jnp.inf, send=None,
                     capacity=None, screen=None, *, k: int, tile_q: int = 8,
-                    tile_d: int = 512, impl: str = "auto"):
+                    tile_d: int | None = None, impl: str = "auto"):
     """Multi-queue fused cycle: every operand carries a leading S axis.
 
     ``states`` is a JaxQueueState of (S, Q)/(S, Q, D)/(S,) arrays; burst

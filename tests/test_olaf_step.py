@@ -20,6 +20,8 @@ import jax.numpy as jnp
 
 from repro.core.olaf_queue import jax_olaf_step, jax_queue_init
 from repro.kernels import ops
+from repro.kernels.olaf_combine import _pick_tile_q
+from repro.kernels.olaf_step import VMEM_BUDGET, derive_tile_d, tile_d_bytes
 
 # the randomized oracle sweeps are long; the CI fast lane skips them
 # (-m "not slow") — the full-suite job still runs this module
@@ -127,17 +129,26 @@ def test_empty_queue_drain_only():
 
 
 @pytest.mark.parametrize("tile_q,tile_d",
-                         [(8, 128), (16, 128), (8, 256), (16, 512)])
+                         [(8, 128), (16, 128), (8, 256), (16, 512),
+                          (8, None)])
 def test_grid_tilings_agree(tile_q, tile_d):
     """Multi-tile grids reuse the first step's resolve + drain-select
     scratch and accumulate the drained rows across Q-tiles; every tiling
     — TPU-legal tiles, D = 300 leaving a partial last D-block — must
-    produce the identical cycle."""
+    produce the identical cycle. ``tile_d=None`` takes the tile derived
+    from the shapes, over a D that spans two whole derived tiles and a
+    partial third, with four updates combining into one slot."""
     rng = np.random.default_rng(0)
     Q, U, Dd, k = 16, 20, 300, 5
+    clusters = rng.integers(0, 12, U)
+    workers = rng.integers(0, 5, U)
+    if tile_d is None:
+        Dd = 2 * derive_tile_d(1 << 30, U, tile_q, k) + 300
+        assert Dd % 128 and -(-Dd // derive_tile_d(Dd, U, tile_q, k)) == 3
+        clusters[:4], workers[:4] = 7, np.arange(4)
     st = jax_queue_init(Q, Dd)
-    args = (jnp.asarray(rng.integers(0, 12, U), jnp.int32),
-            jnp.asarray(rng.integers(0, 5, U), jnp.int32),
+    args = (jnp.asarray(clusters, jnp.int32),
+            jnp.asarray(workers, jnp.int32),
             jnp.asarray(rng.random(U), jnp.float32),
             jnp.asarray(rng.normal(size=U), jnp.float32),
             jnp.asarray(rng.normal(size=(U, Dd)), jnp.float32))
@@ -145,6 +156,40 @@ def test_grid_tilings_agree(tile_q, tile_d):
     got = ops.olaf_step(_copy(st), *args, k=k, impl="pallas",
                         tile_q=tile_q, tile_d=tile_d)
     _assert_cycle_match(want, got, f"tiling({tile_q},{tile_d})")
+    if tile_d is None:
+        counts = np.concatenate([np.asarray(want[0].agg_count),
+                                 np.asarray(want[1]["agg_count"])])
+        assert counts.max() >= 3
+
+
+# (name, Q, U, k, D): the LM PS step (SmolLM-360M at 4 of 32 layers, one
+# flattened gradient a row) and the PPO multi-queue data plane (S = 3
+# switches of the SW1/SW2/SW3 fan-in, Qmax 8, a window of 16; the tile is
+# per queue, so S does not enter) at the CartPole actor-critic's 795
+# floats and at a row wider than one tile
+TILE_SHAPES = [("lm", 4, 4, 2, 86_516_160),
+               ("ppo_multi", 8, 16, 1, 795),
+               ("ppo_multi_wide", 8, 16, 1, 66_000)]
+
+
+@pytest.mark.parametrize("name,Q,U,k,D", TILE_SHAPES,
+                         ids=[t[0] for t in TILE_SHAPES])
+def test_derived_tile_fits_vmem(name, Q, U, k, D):
+    """The derived D-tile is lane-dense (a multiple of 128, or the whole
+    row), its double-buffered blocks and temporaries fit the VMEM budget,
+    and the LM row takes a few thousand grid steps, not 169k."""
+    tile_q = _pick_tile_q(Q, 8)
+    t = derive_tile_d(D, U, tile_q, k)
+    assert t == D or t % 128 == 0
+    assert 0 < t <= D
+    assert tile_d_bytes(t, U, tile_q, k) <= VMEM_BUDGET
+    steps = -(-D // t)
+    if t < D:  # a narrower tile than the budget allows would waste steps
+        assert tile_d_bytes(t + 128, U, tile_q, k) > VMEM_BUDGET
+    if name == "lm":
+        assert steps <= 6000
+    if name == "ppo_multi":
+        assert t == D and steps == 1
 
 
 def test_send_mask_defers_without_dropping():
