@@ -31,6 +31,7 @@ from repro.configs import SHAPES, get_config
 from repro.data.pipeline import DataConfig, SyntheticLM
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models import api
+from repro.models.module import pack_rows, tree_paths, unflatten_like
 from repro.optim.optimizers import OptConfig, apply_updates, init_opt_state
 
 
@@ -71,6 +72,15 @@ def run_sync(cfg, args) -> float:
         save_checkpoint(args.ckpt, args.steps, params, opt_state)
     print(f"final loss {losses[-1]:.4f} (first {losses[0]:.4f})")
     return losses[-1]
+
+
+@jax.jit
+def pack_update(grads):
+    """The burst's worker updates, ``grads`` (a list of same-shaped gradient
+    trees), packed in one executable as the ``(burst, dim)`` float32
+    payloads ``ps_step`` reads: row ``i`` is ``grads[i]``'s leaves raveled
+    and cast to float32 in ``tree_paths`` order (:func:`pack_rows`)."""
+    return pack_rows(grads)
 
 
 @dataclasses.dataclass
@@ -119,7 +129,6 @@ def run_olaf_async(cfg, args) -> OlafAsyncResult:
                                       jax_txctl_gate, jax_txctl_init,
                                       jax_txctl_set_active)
         from repro.kernels import ops
-        from repro.models.module import tree_paths
 
         opt = OptConfig(lr=args.lr, grad_clip=1.0)
         params = api.init_model(jax.random.key(args.seed), cfg)
@@ -160,26 +169,6 @@ def run_olaf_async(cfg, args) -> OlafAsyncResult:
                                          n_shards=args.workers, shard_id=i,
                                          seed=args.seed))
                   for i in range(args.workers)]
-
-        def flatten(tree):
-            return jnp.concatenate([jnp.ravel(v).astype(jnp.float32)
-                                    for v in tree_paths(tree).values()])
-
-        def unflatten_like(flat, like):
-            out, off = {}, 0
-            for k, v in tree_paths(like).items():
-                n = int(np.prod(v.shape))
-                out[k] = flat[off:off + n].reshape(v.shape).astype(v.dtype)
-                off += n
-            # rebuild nested dict
-            root = {}
-            for path, leaf in out.items():
-                d = root
-                parts = path.split("/")
-                for p in parts[:-1]:
-                    d = d.setdefault(p, {})
-                d[parts[-1]] = leaf
-            return root
 
         # workers grouped into clusters
         n_clusters = max(args.workers // 2, 2)
@@ -374,7 +363,7 @@ def run_olaf_async(cfg, args) -> OlafAsyncResult:
             # same-cluster updates meet in the queue and combine (the paper's
             # opportunistic window) — pushed through the fused burst fast
             # path.
-            burst = dict(c=[], w=[], t=[], r=[], p=[])
+            burst = dict(c=[], w=[], t=[], r=[], g=[])
             burst_losses = []
             for _ in range(burst_size):
                 w = int(np.argmin(worker_next))  # next to finish (async)
@@ -388,11 +377,14 @@ def run_olaf_async(cfg, args) -> OlafAsyncResult:
                 burst["t"].append(worker_next[w])
                 burst["r"].append(-loss)
                 with jax.profiler.TraceAnnotation("olaf/pack", worker=w):
-                    burst["p"].append(flatten(grads))
+                    burst["g"].append(grads)
                 burst_losses.append(loss)
                 worker_step[w] += 1
                 worker_next[w] += worker_speed[w]
             with jax.profiler.TraceAnnotation("olaf/ps_step"):
+                # the burst's gradients and their packed payloads are held
+                # by the calls alone, so neither outlives its use
+                del grads
                 (queue, params, opt_state, tx, aom, last_seen, step_key, med,
                  stats) = ps_step(
                     queue, params, opt_state, tx, aom, last_seen, step_key,
@@ -401,7 +393,7 @@ def run_olaf_async(cfg, args) -> OlafAsyncResult:
                     jnp.asarray(burst["w"], jnp.int32),
                     jnp.asarray(burst["t"], jnp.float32),
                     jnp.stack(burst["r"]).astype(jnp.float32),
-                    jnp.stack(burst["p"]), jnp.stack(burst_losses),
+                    pack_update(burst.pop("g")), jnp.stack(burst_losses),
                     jnp.asarray(active_np) if churn else None)
             pending.append(stats)
             if len(pending) >= flush_every:

@@ -96,6 +96,56 @@ def tree_paths(params: Params, prefix: str = "") -> Dict[str, Any]:
     return out
 
 
+# float32 values in one (8, 128) TPU tile
+_TILE = 8 * 128
+
+
+def pack_rows(trees: Sequence[Params]) -> jax.Array:
+    """The ``(len(trees), dim)`` float32 rows of same-shaped trees: row ``i``
+    holds every leaf of ``trees[i]`` raveled and cast to float32, in
+    ``tree_paths`` order, the order :func:`unflatten_like` reads.
+
+    Leaf by leaf, the rows' whole (8, 128) tiles are built as lane-dense
+    ``(n / 128, 128)`` blocks behind an optimization barrier; a TPU holds
+    such a block as the bytes of the contiguous row segment. Without the
+    barrier XLA folds the ravel and the rows' layout into one reshape,
+    which it lowers on a TPU as a relayout loop of several full-length
+    passes. The rest of a leaf, less than a tile, goes as it is."""
+    parts = []
+    for leaves in zip(*(tree_paths(t).values() for t in trees)):
+        rows = [jnp.ravel(v) for v in leaves]
+        n = rows[0].size
+        whole = n - n % _TILE
+        if whole:
+            parts.append(jnp.stack([jax.lax.optimization_barrier(
+                r[:whole].astype(jnp.float32).reshape(-1, 128)).reshape(whole)
+                for r in rows]))
+        if whole < n:
+            parts.append(jnp.stack([r[whole:].astype(jnp.float32)
+                                    for r in rows]))
+    return jnp.concatenate(parts, axis=1)
+
+
+def unflatten_like(flat, like: Params) -> Params:
+    """Slices the flat row ``flat`` back into a nested dict shaped and
+    typed like ``like``, reading the leaves in ``tree_paths`` order, the
+    order :func:`pack_rows` writes them."""
+    out, off = {}, 0
+    for k, v in tree_paths(like).items():
+        n = int(np.prod(v.shape))
+        out[k] = flat[off:off + n].reshape(v.shape).astype(v.dtype)
+        off += n
+    # rebuild nested dict
+    root = {}
+    for path, leaf in out.items():
+        d = root
+        parts = path.split("/")
+        for p in parts[:-1]:
+            d = d.setdefault(p, {})
+        d[parts[-1]] = leaf
+    return root
+
+
 def cast_tree(params: Params, dtype) -> Params:
     return jax.tree.map(lambda x: x.astype(dtype)
                         if jnp.issubdtype(x.dtype, jnp.floating) else x, params)

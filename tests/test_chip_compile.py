@@ -153,3 +153,32 @@ def test_olaf_step_lm_gradient_auto_is_kernel(one_chip, lm_dim, on_tpu):
     # the donated queue is updated in place: one (Q, D) payload, not two
     assert compiled.memory_analysis().alias_size_in_bytes >= \
         LM_Q * lm_dim * 4
+
+
+def test_pack_update_lm_gradient_without_relayout_loop(one_chip):
+    """The trainer's packing of a burst of whole ``smollm-360m`` gradients,
+    in the layouts the worker step returns them (bf16, some leaves
+    transposed), into the ``(burst, dim)`` f32 payloads ``ps_step`` reads
+    compiles without the relayout ``while`` loops that XLA builds for a
+    plain ``concatenate`` of raveled leaves, and with less scratch than the
+    burst itself."""
+    from repro.configs import get_config
+    from repro.launch.train import pack_update
+    from repro.models import api
+
+    cfg = dataclasses.replace(get_config("smollm-360m"), n_layers=LM_LAYERS)
+    shapes = jax.eval_shape(lambda: api.init_model(jax.random.key(0), cfg))
+    params = jax.tree.map(lambda s: _spec(one_chip, s.shape, s.dtype),
+                          shapes)
+    batch = {k: _spec(one_chip, (8, 64), jnp.int32)
+             for k in ("tokens", "labels")}
+    grad_formats = jax.jit(jax.value_and_grad(
+        lambda p, b: api.loss_fn(p, b, cfg))).lower(
+            params, batch).compile().output_formats[1]
+    grads = jax.tree.map(lambda s, f: jax.ShapeDtypeStruct(
+        s.shape, s.dtype, sharding=f), shapes, grad_formats)
+    dim = sum(int(np.prod(x.shape)) for x in jax.tree.leaves(shapes))
+    compiled = pack_update.lower([grads] * LM_U).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < LM_U * dim * 4
+    assert " while(" not in compiled.as_text()
